@@ -201,6 +201,8 @@ def main() -> None:
                     help="CI sizing, JSON artifact to --out")
     ap.add_argument("--out", default="BENCH_faults.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         result = run_faults(n=2048)
         result["smoke"] = True

@@ -62,6 +62,8 @@ def main():
     ap.add_argument("--budget", type=int, default=500)
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(budget=100 if args.quick else args.budget)
 
 

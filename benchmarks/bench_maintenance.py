@@ -120,6 +120,8 @@ def main():
                     help="excess SVs (= merge events) per class")
     ap.add_argument("--out", default="BENCH_maintenance.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         rows = run(dims=(64, 512), budgets=(256,), classes=(1, 16),
                    events=min(args.events, 16), repeats=3)

@@ -152,6 +152,8 @@ def main() -> None:
                     help="CI sizing, JSON artifact to --out")
     ap.add_argument("--out", default="BENCH_online.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         result = run_online(n=4096)
         result["smoke"] = True

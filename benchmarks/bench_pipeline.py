@@ -212,6 +212,8 @@ def main():
                     help="CI sizing (16k stream rows, 8k trace rows)")
     ap.add_argument("--out", default="BENCH_pipeline.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         args.stream_rows, args.chunk_rows = 16384, 2048
         args.train_rows, args.trace_rows = 8192, 8192

@@ -37,6 +37,8 @@ def main():
                     help="CI sizing (4 classes, 2k train / 2k bench rows)")
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         args.n_classes, args.train_rows, args.bench_rows = 4, 2048, 2048
         args.budget, args.max_batch = 32, 64

@@ -10,6 +10,9 @@ measurements run in CHILD processes so each reports its own honest peak RSS
 Rows/sec is a warm second pass (the first pass pays the per-chunk-shape
 compiles); the JSON artifact records both passes, the chunk geometry and the
 RSS split — CI uploads ``BENCH_stream.json`` next to the accuracy bench.
+The parent never imports JAX: a process that has touched JAX holds the
+accelerator, so the shards are written by a child too, and every child
+runs on whatever platform the caller's environment selects.
 """
 from __future__ import annotations
 
@@ -86,14 +89,23 @@ def child_inmem(args) -> dict:
             "peak_rss_mb": _peak_rss_mb()}
 
 
+def child_gen(args) -> dict:
+    import jax
+    import numpy as np
+
+    from repro.data import make_susy_like, write_npz_chunks
+
+    x, y = make_susy_like(jax.random.PRNGKey(1), args.n, args.dim)
+    write_npz_chunks(args.data, np.asarray(x), np.asarray(y), args.chunk_rows)
+    return {"mode": "gen", "n_rows": args.n}
+
+
 def _spawn(mode: str, data_dir: str, args) -> dict:
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")   # never probe TPU from children
     cmd = [sys.executable, "-m", "benchmarks.bench_stream", "--child", mode,
            "--data", data_dir, "--budget", str(args.budget),
-           "--batch-size", str(args.batch_size)]
-    proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                          timeout=1800)
+           "--batch-size", str(args.batch_size), "--n", str(args.n),
+           "--dim", str(args.dim), "--chunk-rows", str(args.chunk_rows)]
+    proc = subprocess.run(cmd, capture_output=True, text=True, timeout=1800)
     if proc.returncode != 0:
         raise RuntimeError(f"{mode} child failed:\n{proc.stdout}\n{proc.stderr}")
     return json.loads(proc.stdout.strip().splitlines()[-1])
@@ -111,30 +123,28 @@ def main():
     ap.add_argument("--out", default="BENCH_stream.json")
     ap.add_argument("--data", default=None,
                     help="existing shard dir (skips generation)")
-    ap.add_argument("--child", default=None, choices=("stream", "inmem"),
-                    help=argparse.SUPPRESS)   # internal: one measurement
+    ap.add_argument("--child", default=None,
+                    choices=("gen", "stream", "inmem"),
+                    help=argparse.SUPPRESS)   # internal: one child's job
     args = ap.parse_args()
 
     if args.child:
-        print(json.dumps(child_stream(args) if args.child == "stream"
-                         else child_inmem(args)))
+        from repro.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
+        child = {"gen": child_gen, "stream": child_stream,
+                 "inmem": child_inmem}[args.child]
+        print(json.dumps(child(args)))
         return
 
     if args.smoke:
         args.n, args.chunk_rows, args.budget = 16384, 2048, 64
 
-    import jax
-    import numpy as np
-
-    from repro.data import make_susy_like, write_npz_chunks
-
     with tempfile.TemporaryDirectory() as tmp:
         data_dir = args.data
         if data_dir is None:
-            x, y = make_susy_like(jax.random.PRNGKey(1), args.n, args.dim)
             data_dir = os.path.join(tmp, "shards")
-            write_npz_chunks(data_dir, np.asarray(x), np.asarray(y),
-                             args.chunk_rows)
+            _spawn("gen", data_dir, args)
         stream = _spawn("stream", data_dir, args)
         inmem = _spawn("inmem", data_dir, args)
 

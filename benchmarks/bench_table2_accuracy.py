@@ -180,6 +180,8 @@ def main():
     ap.add_argument("--out", default="BENCH_table2_accuracy.json",
                     help="JSON output path for --smoke")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         rows = run(n=1200, budgets=(50,), epochs=1, seeds=(0,),
                    datasets=["SUSY", "IJCNN"])

@@ -177,6 +177,8 @@ def main():
     ap.add_argument("--maintenance-only", action="store_true",
                     help="only the isolated maintenance-path microbench")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.maintenance_only:
         maintenance_bench()
         return

@@ -127,6 +127,8 @@ def main():
                          "dim=512/budget=256/C=16)")
     ap.add_argument("--out", default="BENCH_train_step.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         rows = run(repeats=3)
     else:

@@ -167,7 +167,9 @@ def insert_from_rows(cfg: BSGDConfig, state: SVMState, xb, yb, k_b,
     """
     slots = cfg.slots
     active = jnp.arange(state.alpha.shape[0]) < state.count
-    f = k_b.astype(state.alpha.dtype) @ jnp.where(active, state.alpha, 0.0)
+    f = jnp.matmul(k_b.astype(state.alpha.dtype),
+                   jnp.where(active, state.alpha, 0.0),
+                   precision=jax.lax.Precision.HIGHEST)
     margin = yb * f
 
     # optimal dual coordinate step from a = 0 (K_ii = 1): nonzero iff the

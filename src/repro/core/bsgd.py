@@ -233,7 +233,9 @@ def decision_function(state: SVMState, x, gamma, *, impl: str = "auto"):
     """f(x) = sum_j alpha_j k(sv_j, x);  x: (n, d) -> (n,)."""
     k = kops.rbf_matrix(x, state.sv_x, gamma, impl=impl)          # (n, slots)
     active = jnp.arange(state.alpha.shape[0]) < state.count
-    return k @ jnp.where(active, state.alpha, 0.0)
+    # fp32 on every backend (a TPU's default f32 matmul is one bf16 pass)
+    return jnp.matmul(k, jnp.where(active, state.alpha, 0.0),
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def predict(state: SVMState, x, gamma, **kw):
@@ -259,7 +261,9 @@ def insert_from_rows(cfg: BSGDConfig, state: SVMState, xb, yb, k_b,
     # mask by the state's own width: callers may replay a step under a
     # one-larger budget on the same arrays (see bench_table3 decision_stats)
     active = jnp.arange(state.alpha.shape[0]) < state.count
-    f = k_b.astype(state.alpha.dtype) @ jnp.where(active, state.alpha, 0.0)
+    f = jnp.matmul(k_b.astype(state.alpha.dtype),
+                   jnp.where(active, state.alpha, 0.0),
+                   precision=jax.lax.Precision.HIGHEST)
     margin = yb * f
 
     # Pegasos shrink: w <- (1 - eta lambda) w  == alpha *= (1 - 1/t)

@@ -24,7 +24,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..kernels import ops as kops
+from ..kernels import ref as kref
 from . import merge_math
 from .lookup import MergeLookupTable
 
@@ -50,7 +50,9 @@ def _merge_one(k_bh, v_bh, count, gamma, table: MergeLookupTable):
     i_min = jnp.argmin(imp)
     a_min = imp[i_min]
 
-    kappa = kops.rbf_row(k_bh, k_bh[i_min], gamma, impl="ref")
+    # a plain jnp row: this runs vmapped per (batch, head), where the Pallas
+    # rbf kernel's blocks would not tile
+    kappa = kref.rbf_row(k_bh, k_bh[i_min], gamma)
     a_j = jnp.where(active, jnp.linalg.norm(v_bh, axis=-1), 0.0)
     m = jnp.clip(a_min / jnp.where(a_min + a_j == 0, 1.0, a_min + a_j), 0, 1)
     kap = jnp.clip(kappa, 0.0, 1.0)

@@ -30,7 +30,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .bsgd import BSGDConfig, SVMState, train_step
 from .lookup import MergeLookupTable
-from .multiclass import MulticlassSVMConfig, train_step_multiclass
+from .multiclass import (MulticlassSVMConfig, ovr_targets, train_step_ovr,
+                         train_step_multiclass)
 from .predict import ServeModel, predict_labels
 
 
@@ -88,18 +89,38 @@ def multiclass_shardings(cfg: MulticlassSVMConfig, mesh):
             NamedSharding(mesh, P(dp)))
 
 
+def _per_device(step, mesh, state_spec):
+    """``step(state, table, xb, yb)`` as one program per device.
+
+    Each device holds its ``state_spec`` shard of the state and the whole
+    table and minibatch.  GSPMD cannot partition a Mosaic kernel, so running
+    the step per device under ``shard_map`` is what lets the Pallas kernels
+    (``impl``) run on a multi-chip mesh.
+    """
+    return jax.shard_map(step, mesh=mesh,
+                         in_specs=(state_spec, P(), P(), P()),
+                         out_specs=state_spec, check_vma=False)
+
+
 def _make_multiclass_step(cfg: MulticlassSVMConfig, mesh, dim: int,
-                          table: MergeLookupTable | None):
+                          table: MergeLookupTable | None, impl: str):
     b = cfg.binary
     state_sh, x_sh, y_sh = multiclass_shardings(cfg, mesh)
     repl = NamedSharding(mesh, P())
     table_sh = (MergeLookupTable(h_table=repl, wd_table=repl)
                 if table is not None else None)
-
-    def step(state: SVMState, table, xb, yb):
-        return train_step_multiclass(cfg, table, state, xb, yb, impl="ref")
-
     c = cfg.n_classes
+    c_local = c // mesh.shape["model"] if state_sh.count.spec[0] else c
+
+    def local_step(state: SVMState, table, xb, yb):
+        # this device's classes: [first, first + c_local) of the C targets
+        y_ovr = ovr_targets(yb, c, dtype=jnp.dtype(b.dtype))
+        first = jax.lax.axis_index("model") * c_local if c_local < c else 0
+        y_ovr = jax.lax.dynamic_slice_in_dim(y_ovr, first, c_local)
+        return train_step_ovr(b, table, state, xb, y_ovr, impl=impl)
+
+    step = _per_device(local_step, mesh,
+                       jax.tree.map(lambda sh: sh.spec, state_sh))
     args = (
         SVMState(
             sv_x=jax.ShapeDtypeStruct((c, b.slots, dim),
@@ -142,7 +163,7 @@ def serve_shardings(mesh, *, binary: bool = False):
 
 def make_distributed_predict(mesh, *, dim: int, batch: int, slots: int,
                              n_classes: int | None = None,
-                             bank_dtype="bfloat16"):
+                             bank_dtype="bfloat16", impl: str = "auto"):
     """The fused serve cell on the production mesh.
 
     ``n_classes=None`` builds the binary cell (C = 1 bank, ±1 sign labels);
@@ -150,14 +171,16 @@ def make_distributed_predict(mesh, *, dim: int, batch: int, slots: int,
     args_abstract, in_shardings, out_sharding)`` with ``predict_fn(model, x)
     -> labels``; jit it with the shardings and hand it to ``BatchQueue`` as
     ``predict_fn`` (wrapped to close over the resident model) — the queue's
-    bucket set then bounds the pjit cache exactly as on one device.
+    bucket set then bounds the pjit cache exactly as on one device.  Each
+    device scores its request shard (``shard_map``), so the kernels of
+    ``impl`` run unpartitioned.
     """
     binary = n_classes is None
     c = 1 if binary else n_classes
     model_sh, x_sh, y_sh = serve_shardings(mesh, binary=binary)
-
-    def predict_fn(model: ServeModel, x):
-        return predict_labels(model, x, impl="ref")
+    predict_fn = jax.shard_map(
+        lambda model, x: predict_labels(model, x, impl=impl), mesh=mesh,
+        in_specs=(P(), x_sh.spec), out_specs=y_sh.spec, check_vma=False)
 
     args = (
         ServeModel(
@@ -173,11 +196,15 @@ def make_distributed_predict(mesh, *, dim: int, batch: int, slots: int,
 
 def make_distributed_step(cfg, mesh, dim: int,
                           table: MergeLookupTable | None = None,
-                          layout: str = "replicated"):
+                          layout: str = "replicated", impl: str = "auto"):
     """(step_fn, args_abstract, in_shardings, out_shardings).
 
     ``cfg`` is a ``BSGDConfig`` for the binary layouts (``replicated`` /
-    ``slots``) or a ``MulticlassSVMConfig`` for ``layout="class"``.
+    ``slots``) or a ``MulticlassSVMConfig`` for ``layout="class"``.  The
+    step runs once per device (``shard_map``): under ``layout="class"`` on
+    the device's own classes; under the binary layouts on the whole state,
+    which the in/out shardings gather and re-shard around it.  ``impl`` is
+    the kernel implementation, as in ``kernels.ops``.
     """
     if layout == "class":
         if not isinstance(cfg, MulticlassSVMConfig):
@@ -185,7 +212,7 @@ def make_distributed_step(cfg, mesh, dim: int,
                             f"{type(cfg).__name__}")
         if table is None and cfg.binary.method.startswith("lookup"):
             table = cfg.table()
-        return _make_multiclass_step(cfg, mesh, dim, table)
+        return _make_multiclass_step(cfg, mesh, dim, table, impl)
     if table is None and cfg.method.startswith("lookup"):
         table = cfg.table()
     state_sh, x_sh, y_sh = sv_shardings(cfg, mesh, dim, layout=layout)
@@ -193,9 +220,11 @@ def make_distributed_step(cfg, mesh, dim: int,
     table_sh = (MergeLookupTable(h_table=repl, wd_table=repl)
                 if table is not None else None)
 
-    def step(state: SVMState, table, xb, yb):
-        return train_step(cfg, table, state, xb, yb, impl="ref")
+    def whole_step(state: SVMState, table, xb, yb):
+        return train_step(cfg, table, state, xb, yb, impl=impl)
 
+    step = _per_device(whole_step, mesh,
+                       jax.tree.map(lambda _: P(), state_sh))
     args = (
         SVMState(
             sv_x=jax.ShapeDtypeStruct((cfg.slots, dim),
@@ -219,7 +248,8 @@ def make_distributed_step(cfg, mesh, dim: int,
 
 def make_distributed_chunk_step(cfg, mesh, dim: int, chunk_steps: int,
                                 table: MergeLookupTable | None = None,
-                                layout: str = "replicated"):
+                                layout: str = "replicated",
+                                impl: str = "auto"):
     """Per-chunk program for the streaming path on the production mesh.
 
     The streaming trainers (``core.bsgd.fit_stream`` /
@@ -236,7 +266,7 @@ def make_distributed_chunk_step(cfg, mesh, dim: int, chunk_steps: int,
     chunks stream through (``launch.train.svm_stream_loop`` is the driver).
     """
     step, args, in_sh, out_sh = make_distributed_step(cfg, mesh, dim, table,
-                                                      layout=layout)
+                                                      layout=layout, impl=impl)
     state_abs, table_abs, xb_abs, yb_abs = args
     state_sh, table_sh, x_sh, y_sh = in_sh
 

@@ -24,7 +24,10 @@ Invariants (see DESIGN.md §4):
 
   I1. for all ``i, j < count``:  ``kmat[i, j] == k(sv_x[i], sv_x[j])`` up to
       fp tolerance (inserts come from the matmul-decomposition ``rbf_matrix``,
-      merge rows from the log-space combine; both agree to ~1e-6 in fp32);
+      merge rows from the log-space combine; both agree to ~1e-6 in fp32).
+      Merge rows descend from merge rows, so the combine takes its exp and
+      log from ``kernels.ref.exp_f32`` / ``log_f32``: with a TPU's own fp32
+      log the cache drifts by ~1e-2 over 1e5 merges;
   I2. ``kmat`` is exactly symmetric (every update writes row and column from
       the same values);
   I3. ``kmat[i, i] == 1`` for ``i < count`` (set explicitly, never derived);
@@ -47,6 +50,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from ..kernels import ref
 from .merge_math import KAPPA_MIN
 
 
@@ -58,8 +62,6 @@ def init_cache(slots: int, dtype=jnp.float32):
 def exact_cache(sv_x, gamma, dtype=jnp.float32):
     """Ground-truth cache recomputed from the SV set (tests / benchmarks /
     cache (re)builds after checkpoint restore)."""
-    from ..kernels import ref
-
     x = sv_x.astype(jnp.float32)
     k = ref.rbf_matrix(x, x, gamma).astype(dtype)
     # I3: rbf_matrix yields exp(-gamma * eps) on the diagonal, not exactly 1
@@ -67,7 +69,7 @@ def exact_cache(sv_x, gamma, dtype=jnp.float32):
 
 
 def _safe_log(k):
-    return jnp.log(jnp.clip(k.astype(jnp.float32), KAPPA_MIN, 1.0))
+    return ref.log_f32(jnp.clip(k.astype(jnp.float32), KAPPA_MIN, 1.0))
 
 
 def _combine_rows(lk_a, lk_b, lk_ab, h):
@@ -84,7 +86,7 @@ def z_row_from_rows(row_i, row_j, k_ij, h):
     """``k(z, .)`` from the two parents' kernel rows and their pair kernel
     (rows the caller already gathered — lets hot paths batch their gathers)."""
     lz = _combine_rows(_safe_log(row_i), _safe_log(row_j), _safe_log(k_ij), h)
-    return jnp.exp(lz)
+    return ref.exp_f32(lz)
 
 
 def merge_z_row(kmat, i, j, h):
@@ -166,10 +168,10 @@ def apply_multi_merge(kmat, a_idx, b_idx, h, write_idx):
     lk_a, lk_b = lk[:p], lk[p:]                    # (P, slots) each
     lk_ab = lk_a[jnp.arange(p), b_idx]             # (P,) log k(a_p, b_p)
     lz = _combine_rows(lk_a, lk_b, lk_ab[:, None], h[:, None])   # (P, slots)
-    z_rows = jnp.exp(lz).astype(kmat.dtype)
+    z_rows = ref.exp_f32(lz).astype(kmat.dtype)
     # Cross block: z_q = h_q a_q + (1-h_q) b_q, so k(z_p, z_q) combines the
     # z_p row's entries at a_q and b_q with the (a_q, b_q) pair kernel.
-    cross = jnp.exp(_combine_rows(lz[:, a_idx], lz[:, b_idx],
+    cross = ref.exp_f32(_combine_rows(lz[:, a_idx], lz[:, b_idx],
                                   lk_ab[None, :], h[None, :]))
     # k(z_p, z_q) and k(z_q, z_p) take different fp paths; average to keep
     # the cache exactly symmetric (I2), and pin the diagonal (I3).

@@ -33,6 +33,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from ..kernels import ref
+
 # Golden ratio constants.
 INVPHI = (math.sqrt(5.0) - 1.0) / 2.0  # 1/phi ~ 0.618034
 # kappa = exp(-gamma d^2) is clipped away from 0 so log(kappa) stays finite.
@@ -51,9 +53,10 @@ def gss_num_iters(eps: float) -> int:
 
 
 def kappa_pow(kappa, expo):
-    """kappa**expo computed as exp(expo * log kappa), safe at kappa -> 0."""
+    """kappa**expo computed as exp(expo * log kappa), safe at kappa -> 0
+    (with ``kernels.ref``'s few-ulp exp and log)."""
     kappa = jnp.clip(kappa, KAPPA_MIN, 1.0)
-    return jnp.exp(expo * jnp.log(kappa))
+    return ref.exp_f32(expo * ref.log_f32(kappa))
 
 
 def s_objective(h, m, kappa):

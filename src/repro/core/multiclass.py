@@ -168,10 +168,17 @@ def train_step_multiclass(cfg: MulticlassSVMConfig, table, state: SVMState,
     classes fold onto the kernel grid and the cache stays VMEM-resident
     across all three phases (DESIGN.md §12).
     """
-    b = cfg.binary
+    y_ovr = ovr_targets(yb, cfg.n_classes, dtype=jnp.dtype(cfg.binary.dtype))
+    return train_step_ovr(cfg.binary, table, state, xb, y_ovr, impl=impl)
+
+
+def train_step_ovr(b: BSGDConfig, table, state: SVMState, xb, y_ovr, *,
+                   impl: str = "auto") -> SVMState:
+    """``train_step_multiclass`` from one-vs-rest targets ``y_ovr`` of shape
+    (C, batch) in {-1, +1}; ``b`` is the per-class config.  The distributed
+    class layout calls it on each device's slice of the classes."""
     if b.step_engine == "pallas":
         k_bb = kops.rbf_matrix(xb, xb, b.gamma, impl=impl)
-        y_ovr = ovr_targets(yb, cfg.n_classes, dtype=jnp.dtype(b.dtype))
         sv, al, km, cnt, st_, nin, nmg = kops.train_step(
             state.sv_x, state.alpha, state.kmat, state.count, state.step,
             state.n_inserts, state.n_merges, xb, y_ovr, k_bb, table,
@@ -184,7 +191,6 @@ def train_step_multiclass(cfg: MulticlassSVMConfig, table, state: SVMState,
     k_b = class_kernel_rows(state.sv_x, xb, b.gamma, impl=impl)   # (C, batch, slots)
     k_bb = (kops.rbf_matrix(xb, xb, b.gamma, impl=impl)
             if b.use_kernel_cache else None)
-    y_ovr = ovr_targets(yb, cfg.n_classes, dtype=jnp.dtype(b.dtype))
 
     # the §14 solver contract: a solver is an (insert+update, full-step) pair
     # with bsgd's row-consuming signatures; everything downstream — the class

@@ -28,12 +28,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 WD_INVALID = 3.4e38  # python float: jnp constants would be captured by the kernel
+# Every matmul of this package that carries values (not 0/1 counts) asks
+# for an fp32 contraction rather than the backend's default, which on a TPU
+# may be a single bf16 pass.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _hat_weights(coord, g: int):
     """(bS,) unit-interval coords -> (bS, G) hat-basis weights (2 nonzeros/row)."""
     u = jnp.clip(coord, 0.0, 1.0) * (g - 1)
-    iota = jax.lax.broadcasted_iota(jnp.float32, (coord.shape[0], g), 1)
+    # Mosaic only builds integer iotas: make an int32 one and cast it
+    iota = jax.lax.broadcasted_iota(jnp.int32, (coord.shape[0], g),
+                                    1).astype(jnp.float32)
     return jnp.maximum(0.0, 1.0 - jnp.abs(u[:, None] - iota))
 
 
@@ -53,6 +59,7 @@ def _merge_score_kernel(alpha_ref, kappa_ref, valid_ref, amin_ref, table_ref,
     w_k = _hat_weights(kap, g)                        # (bS, G)
     # Gather-free bilinear: rowsum((W_m @ T) * W_k); the matmul hits the MXU.
     rows = jax.lax.dot_general(w_m, table, (((1,), (0,)), ((), ())),
+                               precision=HIGHEST,
                                preferred_element_type=jnp.float32)  # (bS, G)
     interp = jnp.sum(rows * w_k, axis=1)              # (bS,)
 

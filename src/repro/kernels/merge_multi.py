@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .merge_lookup import WD_INVALID, _hat_weights
+from .merge_lookup import HIGHEST, WD_INVALID, _hat_weights
 
 P_PAD = 8  # fp32 sublane multiple; ops pads the pair axis to this
 
@@ -53,8 +53,10 @@ def _multi_merge_kernel(alpha_ref, kappa_ref, valid_ref, amin_ref,
     # One MXU matmul per table for all P rows; rowsum against w_k finishes
     # the bilinear interpolation without a single gather.
     rows_wd = jax.lax.dot_general(w_m, wd_tab_ref[...], (((1,), (0,)), ((), ())),
+                                  precision=HIGHEST,
                                   preferred_element_type=jnp.float32)
     rows_h = jax.lax.dot_general(w_m, h_tab_ref[...], (((1,), (0,)), ((), ())),
+                                 precision=HIGHEST,
                                  preferred_element_type=jnp.float32)
     interp_wd = jnp.sum(rows_wd * w_k, axis=1).reshape(p, bs)
     interp_h = jnp.sum(rows_h * w_k, axis=1).reshape(p, bs)

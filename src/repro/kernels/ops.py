@@ -115,7 +115,8 @@ def class_scores(x, sv_x, alpha, gamma, *, impl: str = "auto"):
     c, slots, d = sv_x.shape
     k = rbf_matrix(x, sv_x.reshape(c * slots, d), gamma, impl=impl)
     k = k.reshape(x.shape[0], c, slots)
-    return jnp.einsum("ncs,cs->cn", k.astype(alpha.dtype), alpha)
+    return jnp.einsum("ncs,cs->cn", k.astype(alpha.dtype), alpha,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 # --------------------------------------------------------------------------
@@ -183,15 +184,15 @@ def merge_event(sv_x, alpha, kmat, count, over, table, *, impl: str = "auto",
     if impl == "ref":
         return ref.merge_event(sv_x, alpha, kmat, count, over,
                                table.h_table, table.wd_table)
-    c, s, d = sv_x.shape
+    _, s, d = sv_x.shape
     sv_p = _pad_to_lane(sv_x, (1, 2))
     al_p = _pad_to_lane(alpha, 1)
     km_p = _pad_to_lane(kmat, (1, 2))
     sv_n, al_n, km_n = merge_event_kernel.merge_event_pallas(
-        sv_p, al_p, km_p, count.reshape(c, 1).astype(jnp.int32),
-        over.reshape(c, 1).astype(jnp.int32), table.h_table, table.wd_table,
+        sv_p, al_p[:, None, :], km_p, count.astype(jnp.int32),
+        over.astype(jnp.int32), table.h_table, table.wd_table,
         block_s=block_s, interpret=(impl == "pallas_interpret"))
-    return sv_n[:, :s, :d], al_n[:, :s], km_n[:, :s, :s]
+    return sv_n[:, :s, :d], al_n[:, 0, :s], km_n[:, :s, :s]
 
 
 # --------------------------------------------------------------------------
@@ -313,22 +314,20 @@ def train_step(sv_x, alpha, kmat, count, step, n_inserts, n_merges, xb, yb,
             k_bb, table.h_table, table.wd_table, budget=budget,
             lambda_=lambda_, gamma=gamma, batch_size=batch_size,
             maintenance=maintenance, merge_batch=merge_batch, unroll=unroll)
-    c, s, d = sv_x.shape
+    _, s, d = sv_x.shape
     sv_p = _pad_to_lane(sv_x, (1, 2))
     al_p = _pad_to_lane(alpha, 1)
     km_p = _pad_to_lane(kmat, (1, 2))
     xb_p = _pad_to_lane(xb, (0, 1))
     kbb_p = _pad_to_lane(k_bb, (0, 1))
     yb_p = _pad_to_lane(yb, 1)
-    as_col = lambda a: a.reshape(c, 1).astype(jnp.int32)
     sv_n, al_n, km_n, cnt_n, nins_n, nmrg_n = \
         train_step_kernel.train_step_pallas(
-            sv_p, al_p, km_p, as_col(count), as_col(step),
-            as_col(n_inserts), as_col(n_merges), xb_p, yb_p, kbb_p,
-            table.h_table, table.wd_table, budget=budget, lambda_=lambda_,
-            gamma=gamma, batch_size=batch_size, rounds=batch_size,
+            sv_p, al_p[:, None, :], km_p, count, step, n_inserts, n_merges,
+            xb_p, yb_p[:, None, :], kbb_p, table.h_table, table.wd_table,
+            budget=budget, lambda_=lambda_, gamma=gamma,
+            batch_size=batch_size, rounds=batch_size,
             maintenance=maintenance, merge_batch=merge_batch,
             block_s=block_s, interpret=(impl == "pallas_interpret"))
-    return (sv_n[:, :s, :d], al_n[:, :s], km_n[:, :s, :s],
-            cnt_n.reshape(c), step + 1, nins_n.reshape(c),
-            nmrg_n.reshape(c))
+    return (sv_n[:, :s, :d], al_n[:, 0, :s], km_n[:, :s, :s], cnt_n,
+            step + 1, nins_n, nmrg_n)

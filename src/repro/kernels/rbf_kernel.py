@@ -42,6 +42,7 @@ def _rbf_block_kernel(x_ref, y_ref, gamma_ref, o_ref, *, n_k: int):
     xn = jnp.sum(x * x, axis=1, keepdims=True)          # (bn, 1)   VPU
     yn = jnp.sum(y * y, axis=1, keepdims=True).T        # (1, bm)   VPU
     xy = jax.lax.dot_general(x, y, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)  # (bn, bm) MXU
     o_ref[...] += xn + yn - 2.0 * xy
 

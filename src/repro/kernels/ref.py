@@ -6,6 +6,9 @@ fallback (XLA compiles them well); the Pallas kernels target TPU.
 """
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -18,7 +21,9 @@ def rbf_matrix(x, y, gamma):
     """
     xn = jnp.sum(x * x, axis=-1)[:, None]
     yn = jnp.sum(y * y, axis=-1)[None, :]
-    d2 = xn + yn - 2.0 * x @ y.T
+    # fp32 on every backend: XLA's default f32 matmul on a TPU is one bf16 pass
+    xy = jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)
+    d2 = xn + yn - 2.0 * xy
     return jnp.exp(-gamma * jnp.maximum(d2, 0.0))
 
 
@@ -110,7 +115,8 @@ def class_scores(x, sv_x, alpha, gamma):
     the fused ``ops.class_scores`` fold is tested against.
     """
     return jnp.stack([
-        rbf_matrix(x, sv_x[c], gamma).astype(alpha.dtype) @ alpha[c]
+        jnp.matmul(rbf_matrix(x, sv_x[c], gamma).astype(alpha.dtype),
+                   alpha[c], precision=jax.lax.Precision.HIGHEST)
         for c in range(sv_x.shape[0])])
 
 
@@ -130,14 +136,72 @@ NO_PARTNER = 1e30
 # duplicated so the kernels package stays import-clean).
 _KAPPA_MIN = 1e-30
 
+# The merge math below derives new cache rows and alphas from old ones, merge
+# after merge, so an error in exp or log is inherited by every descendant.
+# A TPU's fp32 log is off by up to ~4e-4 relative (exp by ~5e-6), which
+# drifts the kernel cache by ~1e-2 over 1e5 merges; these two are built from
+# adds, multiplies and exponent bit moves, so they are within a few ulp on
+# every backend, inside Pallas kernels too.
+_LN2_HI = 0.693145751953125          # ln 2 in 15 bits: n * _LN2_HI is exact
+_LN2_LO = math.log(2.0) - _LN2_HI
+_SQRT2 = math.sqrt(2.0)
+
+
+def _on_vectors(fn):
+    """Mosaic bitcasts vectors only: lift a scalar to (1, 1) and back."""
+    def wrapped(x):
+        x = jnp.asarray(x, jnp.float32)
+        if x.ndim:
+            return fn(x)
+        return fn(jnp.broadcast_to(x, (1, 1)))[0, 0]
+    return functools.wraps(fn)(wrapped)
+
+
+@_on_vectors
+def log_f32(x):
+    """Natural log of positive, normal fp32 ``x`` to a few ulp.
+
+    ``x = m 2^e`` with ``m`` in [sqrt(1/2), sqrt(2)], read off the bits;
+    ``log m = 2 atanh(s)``, ``s = (m - 1) / (m + 1)``, ``|s| <= 0.172``, by
+    its series to ``s^11``.
+    """
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    m = jax.lax.bitcast_convert_type((bits & 0x007FFFFF) | 0x3F800000,
+                                     jnp.float32)              # [1, 2)
+    big = m > _SQRT2
+    m = jnp.where(big, 0.5 * m, m)
+    e = ((bits >> 23) - 127 + big.astype(jnp.int32)).astype(jnp.float32)
+    f = m - 1.0
+    s = f / (2.0 + f)
+    z = s * s
+    t = z * (1 / 3 + z * (1 / 5 + z * (1 / 7 + z * (1 / 9 + z * (1 / 11)))))
+    return e * _LN2_HI + (e * _LN2_LO + (2.0 * s + 2.0 * s * t))
+
+
+@_on_vectors
+def exp_f32(y):
+    """``e**y`` in fp32 to a few ulp, for ``y`` in [-87, 88] (clamped).
+
+    ``y = n ln2 + r`` with ``|r| <= ln2 / 2``; ``e**r`` by its Taylor
+    polynomial to ``r^7``, scaled by ``2^n`` written into the exponent bits.
+    """
+    y = jnp.clip(y, -87.0, 88.0)
+    n = jnp.floor(y * (1.0 / math.log(2.0)) + 0.5)
+    r = (y - n * _LN2_HI) - n * _LN2_LO
+    p = 1.0 + r * (1.0 + r * (1 / 2 + r * (1 / 6 + r * (
+        1 / 24 + r * (1 / 120 + r * (1 / 720 + r * (1 / 5040)))))))
+    scale = jax.lax.bitcast_convert_type(
+        (n.astype(jnp.int32) + 127) << 23, jnp.float32)
+    return p * scale
+
 
 def _safe_log(k):
-    return jnp.log(jnp.clip(k.astype(jnp.float32), _KAPPA_MIN, 1.0))
+    return log_f32(jnp.clip(k.astype(jnp.float32), _KAPPA_MIN, 1.0))
 
 
 def _kappa_pow(kappa, expo):
     """kappa**expo as exp(expo log kappa) — core.merge_math.kappa_pow."""
-    return jnp.exp(expo * _safe_log(kappa))
+    return exp_f32(expo * _safe_log(kappa))
 
 
 def merge_event(sv_x, alpha, kmat, count, over, h_table, wd_table):
@@ -212,7 +276,7 @@ def merge_event(sv_x, alpha, kmat, count, over, h_table, wd_table):
     lz = (h_m[:, None] * _safe_log(kappa_row)
           + (1.0 - h_m[:, None]) * _safe_log(row_j)
           - (h_m * (1.0 - h_m))[:, None] * _safe_log(k_ij)[:, None])
-    z_row = jnp.exp(jnp.minimum(lz, 0.0)).astype(kmat.dtype)
+    z_row = exp_f32(jnp.minimum(lz, 0.0)).astype(kmat.dtype)
 
     # 5. masked two-row + two-column update (budget._merge_once's fused
     # branch-free form, batched over classes): slot t1 <- z row (or, on the
@@ -322,9 +386,9 @@ def _multi_merge_event_one(sv_x, alpha, kmat, count, h_table, wd_table, *,
     lz = jnp.minimum(hc * lk_a + (1.0 - hc) * lk_b
                      - (h_star * (1.0 - h_star))[:, None] * lk_ab[:, None],
                      0.0)
-    z_rows = jnp.exp(lz).astype(kmat.dtype)
+    z_rows = exp_f32(lz).astype(kmat.dtype)
     hr = h_star[None, :]
-    cross = jnp.exp(jnp.minimum(
+    cross = exp_f32(jnp.minimum(
         hr * lz[:, a_idx] + (1.0 - hr) * lz[:, b_idx]
         - (h_star * (1.0 - h_star))[None, :] * lk_ab[None, :], 0.0))
     cross = 0.5 * (cross + cross.T)
@@ -416,7 +480,8 @@ def train_step_fused(sv_x, alpha, kmat, count, step, n_inserts, n_merges,
     def insert_one(sv, al, km, cnt, t, nin, yc, kb):
         eta = 1.0 / (lambda_ * t)
         active = jnp.arange(slots) < cnt
-        f = kb.astype(al.dtype) @ jnp.where(active, al, 0.0)
+        f = jnp.matmul(kb.astype(al.dtype), jnp.where(active, al, 0.0),
+                       precision=jax.lax.Precision.HIGHEST)
         margin = yc * f
         al = al * (1.0 - eta * lambda_)
         viol = margin < 1.0

@@ -6,11 +6,8 @@ import jax
 
 
 def make_mesh(shape, axes):
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    # older jax (< 0.5) has no AxisType; plain meshes are Auto already
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,9 +1,7 @@
 """Three-term roofline analysis from the compiled dry-run artifact.
 
-TPU v5e constants (the target, not the runtime):
-    peak bf16 compute : 197 TFLOP/s per chip
-    HBM bandwidth     : 819 GB/s per chip
-    ICI               : ~50 GB/s per link
+Device peaks come from ``PEAKS``, keyed by ``jax.Device.device_kind``; the
+dry-run plans for ``TARGET_DEVICE_KIND`` (TPU v5e) whatever it runs on.
 
 Terms (per assignment):
     compute_s    = HLO_FLOPs / peak            (cost_analysis is per-device
@@ -22,10 +20,34 @@ import dataclasses
 import json
 import re
 
-PEAK_FLOPS = 197e12        # bf16 FLOP/s per chip
-HBM_BW = 819e9             # B/s per chip
-ICI_BW = 50e9              # B/s per link
-HBM_BYTES = 16 * 1024**3   # v5e HBM per chip
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    flops: float        # bf16 FLOP/s per chip
+    hbm_bw: float       # HBM bytes/s per chip
+    ici_bw: float       # interconnect bytes/s per link
+    hbm_bytes: int      # HBM capacity per chip
+
+
+# Published per-chip peaks by ``device_kind``.  TPU v5e: Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s and 16 GB of HBM,
+# 1,600 Gbit/s of interconnect over 4 links (50 GB/s each).  JAX reports a
+# v5e as "TPU v5 lite".
+_V5E = DevicePeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                   hbm_bytes=16 * 1024**3)
+PEAKS = {"TPU v5 lite": _V5E, "TPU v5e": _V5E}
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> DevicePeaks:
+    """The published peaks of ``device_kind``; an unknown device is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -132,11 +154,13 @@ class Roofline:
     fits_hbm: bool = True
     step_s: float = 0.0
     roofline_frac: float = 0.0
+    device_kind: str = TARGET_DEVICE_KIND
 
     def finalize(self):
-        self.compute_s = self.flops_per_dev / PEAK_FLOPS
-        self.memory_s = self.bytes_per_dev / HBM_BW
-        self.collective_s = self.coll_bytes_per_dev / ICI_BW
+        pk = peaks(self.device_kind)
+        self.compute_s = self.flops_per_dev / pk.flops
+        self.memory_s = self.bytes_per_dev / pk.hbm_bw
+        self.collective_s = self.coll_bytes_per_dev / pk.ici_bw
         terms = {"compute": self.compute_s, "memory": self.memory_s,
                  "collective": self.collective_s}
         self.dominant = max(terms, key=terms.get)
@@ -145,10 +169,11 @@ class Roofline:
                              if hlo_global else 0.0)
         # fit decided on args + analytic activations: CPU temp is an artifact
         # of CPU buffer liveness, reported but not used for the verdict.
-        self.fits_hbm = (self.act_bytes_est + self.arg_bytes_per_dev) <= HBM_BYTES
+        self.fits_hbm = (self.act_bytes_est
+                         + self.arg_bytes_per_dev) <= pk.hbm_bytes
         # overlap model: compute overlaps with memory AND collectives at best
         self.step_s = max(terms.values())
-        ideal_s = self.model_flops_global / (self.n_devices * PEAK_FLOPS)
+        ideal_s = self.model_flops_global / (self.n_devices * pk.flops)
         self.roofline_frac = ideal_s / self.step_s if self.step_s else 0.0
         return self
 
@@ -174,8 +199,6 @@ def analyze(compiled, *, arch: str, shape: str, mesh, strategy: str,
             model_flops_global: float, hlo_text: str | None = None,
             act_bytes: float = 0.0) -> Roofline:
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):       # newer jax: one dict per program
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     text = hlo_text if hlo_text is not None else compiled.as_text()
     coll = collective_bytes(text)

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..configs import get, get_smoke
 from ..models import decode_step, init_cache, init_lm, prefill
 from .mesh import make_host_mesh
@@ -348,6 +349,7 @@ def main() -> None:
                          "supervisor restart")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.arch == "svm_bsgd" and args.live:
         faults = None
         if args.faults is not None:

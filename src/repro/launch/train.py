@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import checkpoint as ckpt
+from ..compile_cache import enable_compile_cache
 from ..configs import get, get_smoke
 from ..data.tokens import BigramStream, frames_batch
 from ..models import init_lm
@@ -166,11 +167,14 @@ def svm_stream_loop(source, *, layout: str = "replicated", n_classes: int = 8,
                                    init_multiclass_state)
     from .mesh import make_mesh
 
+    is_class = layout == "class"
     if mesh is None:
-        mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
+        # classes shard over ``model``, so the class layout puts the devices
+        # there; the binary layouts shard the batch over ``data``
+        n = len(jax.devices())
+        mesh = make_mesh((1, n) if is_class else (n, 1), ("data", "model"))
     bcfg = BSGDConfig(budget=budget, lambda_=lambda_, gamma=gamma,
                       method=method, batch_size=batch_size)
-    is_class = layout == "class"
     cfg = (MulticlassSVMConfig(n_classes=n_classes, binary=bcfg) if is_class
            else bcfg)
     table = cfg.table()
@@ -269,6 +273,7 @@ def main() -> None:
                          "roll back to the last good state and skip the "
                          "offending chunk instead of training on NaN/Inf")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.arch == "svm_bsgd":
         if not args.stream:
             raise SystemExit("--arch svm_bsgd needs --stream PATH")

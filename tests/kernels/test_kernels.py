@@ -3,6 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from repro.core import default_table
 from repro.kernels import ops, ref
@@ -22,6 +23,39 @@ def test_rbf_matrix_matches_ref(n, m, d, dtype):
     tol = 1e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("fn", ["log_f32", "exp_f32"])
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_exp_log_f32_within_few_ulp(fn, impl):
+    """The merge math's exp and log, against float64, over the ranges the
+    cache feeds them (kappa in [1e-30, 1]; log-kernel values in [-69, 0]),
+    exact at 1 and 0, and the same in XLA and in a Pallas kernel."""
+    rng = np.random.default_rng(0)
+    if fn == "log_f32":
+        x = 10.0 ** rng.uniform(-30, 0, (64, 1024))
+        x[0, :512] = 1.0 - rng.uniform(0, 1e-3, 512)
+        x[0, 0], exact = 1.0, np.log
+    else:
+        x = rng.uniform(-69, 0, (64, 1024))
+        x[0, :512] = -rng.uniform(0, 1e-3, 512)
+        x[0, 0], exact = 0.0, np.exp
+    x = x.astype(np.float32)
+    want = exact(x.astype(np.float64))
+    ulp = np.spacing(np.abs(want).astype(np.float32)).astype(np.float64)
+    f = getattr(ref, fn)
+    if impl == "xla":
+        got = jax.jit(f)(x)
+    else:
+        def kern(x_ref, o_ref):
+            o_ref[...] = f(x_ref[...])
+            o_ref[0, 1] = f(x_ref[0, 1])          # a scalar takes the same path
+        got = pl.pallas_call(kern, out_shape=jax.ShapeDtypeStruct(x.shape,
+                                                                  jnp.float32),
+                             interpret=True)(x)
+    got = np.asarray(got).astype(np.float64)
+    assert np.all(np.abs(got - want) <= 4 * ulp)
+    assert got[0, 0] == want[0, 0]
 
 
 @pytest.mark.parametrize("gamma", [0.01, 1.0, 30.0])
