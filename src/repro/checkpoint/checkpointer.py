@@ -34,6 +34,8 @@ from typing import Any
 import jax
 import numpy as np
 
+from .. import obs
+
 _SEP = "/"
 
 
@@ -65,14 +67,32 @@ def _flatten(tree) -> dict[str, Any]:
 
 def save(ckpt_dir: str, step: int, tree, *, keep_last: int = 3,
          metadata: dict | None = None) -> str:
-    """Atomic synchronous save; returns the final directory path."""
+    """Atomic synchronous save; returns the final directory path.
+
+    One ``ckpt.save`` span (``repro.obs``) over ``ckpt.sync`` (waiting for
+    the tree's device arrays to be computed), ``ckpt.copy`` (device to
+    host) and ``ckpt.write`` (everything on disk); the last two carry the
+    tree's ``bytes``.
+    """
+    with obs.span("ckpt.save"):
+        with obs.span("ckpt.sync"):
+            jax.block_until_ready(tree)
+        with obs.span("ckpt.copy") as copy:
+            flat = {k: np.asarray(v) for k, v in _flatten(tree).items()}
+            nbytes = sum(v.nbytes for v in flat.values())
+            copy.set(bytes=nbytes)
+        with obs.span("ckpt.write", bytes=nbytes):
+            return _write(ckpt_dir, step, flat, keep_last, metadata)
+
+
+def _write(ckpt_dir: str, step: int, flat: dict, keep_last: int,
+           metadata: dict | None) -> str:
     os.makedirs(ckpt_dir, exist_ok=True)
     final = os.path.join(ckpt_dir, f"step_{step:08d}")
     tmp = final + ".tmp"
     if os.path.exists(tmp):
         shutil.rmtree(tmp)
     os.makedirs(tmp)
-    flat = {k: np.asarray(v) for k, v in _flatten(tree).items()}
     arrays_path = os.path.join(tmp, "arrays.npz")
     np.savez(arrays_path, **flat)
     manifest = {

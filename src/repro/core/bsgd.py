@@ -30,6 +30,7 @@ import numpy as np
 
 from . import budget as budget_mod
 from . import kernel_cache
+from .. import obs
 from .lookup import MergeLookupTable, default_table
 from ..kernels import ops as kops
 
@@ -447,7 +448,8 @@ def train_chunk(cfg: BSGDConfig, table, state: SVMState, xc, yc, *,
         xb, yb = xy
         return train_step(cfg, table, st, xb, yb, impl=impl), ()
 
-    state, _ = jax.lax.scan(body, state, (xc, yc))
+    with jax.named_scope("train_chunk"):
+        state, _ = jax.lax.scan(body, state, (xc, yc))
     return state
 
 
@@ -491,7 +493,8 @@ def _assemble_chunks(source, key, *, batch_size: int, start_chunk: int,
             xc = x[:used].reshape(steps, batch_size, x.shape[1])
             yc = y[:used].reshape(steps, batch_size)
             if stage is not None:
-                xc, yc = stage(xc, yc)
+                with obs.span("stream.stage"):
+                    xc, yc = stage(xc, yc)
         yield pos, xc, yc, (cx, cy)
 
 
@@ -514,22 +517,31 @@ def _stage_chunks(gen, depth: int):
     _DONE, _FAIL = object(), object()
 
     def _put(item) -> bool:
-        while not stop.is_set():
-            try:
-                q.put(item, timeout=0.1)
-                return True
-            except queue_mod.Full:
-                continue
+        try:
+            q.put_nowait(item)
+            return True
+        except queue_mod.Full:
+            pass
+        with obs.span("stream.backpressure"):
+            while not stop.is_set():
+                try:
+                    q.put(item, timeout=0.1)
+                    return True
+                except queue_mod.Full:
+                    continue
         return False
 
+    ctx = obs.context()
+
     def work():
-        try:
-            for item in gen:
-                if not _put((None, item)):
-                    return
-            _put((_DONE, None))
-        except BaseException as e:  # noqa: BLE001 — re-raised on consumer
-            _put((_FAIL, e))
+        with obs.attach(ctx):
+            try:
+                for item in gen:
+                    if not _put((None, item)):
+                        return
+                _put((_DONE, None))
+            except BaseException as e:  # noqa: BLE001 — re-raised on
+                _put((_FAIL, e))            # the consumer's thread
 
     t = threading.Thread(target=work, daemon=True, name="chunk-stager")
     t.start()
@@ -593,7 +605,7 @@ def _stream_epoch(chunk_fn, state, source, *, batch_size: int, key,
                   start_chunk: int = 0, carry=None, on_chunk=None,
                   max_chunks: int | None = None, prefetch: int = 0,
                   stage=None, retry=None, report=None, skip_chunks=(),
-                  guard=None):
+                  guard=None, epoch: int = 0):
     """Generic one-epoch streaming driver shared by binary and multi-class.
 
     ``chunk_fn(state, xc, yc) -> state`` runs one jitted chunk program.
@@ -625,6 +637,11 @@ def _stream_epoch(chunk_fn, state, source, *, batch_size: int, key,
     published ``ServeModel`` snapshots — the rollback fires BEFORE
     ``on_chunk``.
 
+    Each chunk is one ``fit.chunk`` span (``repro.obs``; attributes
+    ``epoch``, ``pos``, ``rows``) over its ``stream.wait`` for the staged
+    chunk, its ``chunk.launch``, the guard's ``guard.finite`` and
+    ``on_chunk``.
+
     Returns ``(state, next_chunk, carry, chunks_run)``; ``next_chunk <
     source.n_chunks`` means the epoch was cut short by ``max_chunks``.
     """
@@ -639,25 +656,41 @@ def _stream_epoch(chunk_fn, state, source, *, batch_size: int, key,
     items = _stage_chunks(gen, prefetch) if prefetch else gen
     out_carry = carry
     try:
-        for pos, xc, yc, out_carry in items:
-            if xc is not None:
-                if guard is not None and guard.finite:
-                    # the chunk program donates its input state, so the
-                    # last-good snapshot must be copied out BEFORE the launch
-                    snap = jax.tree.map(jnp.copy, state)
-                    new_state = chunk_fn(state, xc, yc)
-                    if bool(_tree_all_finite(new_state)):
-                        state = new_state
+        while True:
+            with obs.span("fit.chunk") as chunk:
+                with obs.span("stream.wait") as wait:
+                    item = next(items, None)
+                    if item is None:          # the epoch's end, no chunk
+                        wait.discard()
+                        chunk.discard()
+                if item is None:
+                    break
+                pos, xc, yc, out_carry = item
+                chunk.set(epoch=epoch, pos=pos,
+                          rows=0 if xc is None else xc.shape[0] * xc.shape[1])
+                if xc is not None:
+                    if guard is not None and guard.finite:
+                        # the chunk program donates its input state, so the
+                        # last-good snapshot must be copied out BEFORE the
+                        # launch
+                        snap = jax.tree.map(jnp.copy, state)
+                        with obs.span("chunk.launch"):
+                            new_state = chunk_fn(state, xc, yc)
+                        with obs.span("guard.finite"):
+                            finite = bool(_tree_all_finite(new_state))
+                        if finite:
+                            state = new_state
+                        else:
+                            state = snap   # roll back + skip the poisoned
+                            if guard.report is not None:  # chunk wholesale
+                                guard.report.note_rollback(pos)
                     else:
-                        state = snap       # roll back + skip the poisoned
-                        if guard.report is not None:      # chunk wholesale
-                            guard.report.note_rollback(pos)
-                else:
-                    state = chunk_fn(state, xc, yc)
-                if guard is not None and guard.check is not None:
-                    guard.check(state)
-            if on_chunk is not None:
-                on_chunk(state, pos, out_carry)
+                        with obs.span("chunk.launch"):
+                            state = chunk_fn(state, xc, yc)
+                    if guard is not None and guard.check is not None:
+                        guard.check(state)
+                if on_chunk is not None:
+                    on_chunk(state, pos, out_carry)
     finally:
         if prefetch:
             items.close()                 # stop the stager on any exit
@@ -707,95 +740,104 @@ def _fit_stream(batch_size: int, source, chunk_fn, state, *,
     (``checkpoint.latest_verifiable_step``); ``retry``/``report``/
     ``skip_chunks``/``guard`` are the §16 resilience hooks threaded into
     every epoch."""
-    from .. import checkpoint as ckpt
+    with obs.span("fit.stream"):
+        from .. import checkpoint as ckpt
 
-    dim = source.dim
-    n_chunks = source.n_chunks
-    start_epoch, start_chunk = 0, 0
-    carry, resume_key = None, None
-    if ckpt_dir:
-        latest = ckpt.latest_step(ckpt_dir)
-        if latest is not None:
-            # a torn/bit-flipped newest step (crash mid-save outside the
-            # atomic-rename path, disk corruption) must not kill the restart:
-            # fall back to the newest step whose checksums verify
-            verified = ckpt.latest_verifiable_step(ckpt_dir)
-            if verified is None:
-                raise ValueError(
-                    f"{ckpt_dir}: checkpoint steps {ckpt.all_steps(ckpt_dir)}"
-                    " exist but none verify (manifest/arrays corrupt) — "
-                    "refusing to silently restart from scratch")
-            latest = verified
-        if latest is not None:
-            meta = ckpt.load_metadata(ckpt_dir, latest)
-            if meta.get("kind") != "stream-epoch":
-                raise ValueError(f"{ckpt_dir}: step {latest} is not a "
-                                 "streaming checkpoint")
-            # the cursor is only meaningful against the same shuffle and the
-            # same chunking — a silent mismatch would train some rows twice
-            # and others never, so refuse instead
-            if meta["seed"] != seed:
-                raise ValueError(
-                    f"{ckpt_dir}: checkpoint was written with seed="
-                    f"{meta['seed']}, resume called with seed={seed}")
-            if meta["n_chunks"] != n_chunks:
-                raise ValueError(
-                    f"{ckpt_dir}: checkpoint cursor is against "
-                    f"{meta['n_chunks']} chunks, source now has {n_chunks} — "
-                    "re-chunked data cannot resume mid-epoch")
-            tree = ckpt.load(ckpt_dir, latest,
-                             _ckpt_template(state, batch_size, dim))
-            state = tree["state"]
-            start_epoch, start_chunk = meta["epoch"], meta["next_chunk"]
-            resume_key = tree["epoch_key"]    # the interrupted epoch's key
-            cn = int(tree["carry_n"])
-            carry = (np.asarray(tree["carry_x"])[:cn],
-                     np.asarray(tree["carry_y"])[:cn])
-            if start_chunk >= n_chunks:       # checkpoint at an epoch boundary
-                start_epoch, start_chunk, carry = start_epoch + 1, 0, None
-                resume_key = None
+        dim = source.dim
+        n_chunks = source.n_chunks
+        start_epoch, start_chunk = 0, 0
+        carry, resume_key = None, None
+        if ckpt_dir:
+            latest = ckpt.latest_step(ckpt_dir)
+            if latest is not None:
+                # a torn/bit-flipped newest step (crash mid-save outside the
+                # atomic-rename path, disk corruption) must not kill the
+                # restart: fall back to the newest step whose checksums verify
+                verified = ckpt.latest_verifiable_step(ckpt_dir)
+                if verified is None:
+                    raise ValueError(
+                        f"{ckpt_dir}: checkpoint steps "
+                        f"{ckpt.all_steps(ckpt_dir)} exist but none verify "
+                        "(manifest/arrays corrupt) — "
+                        "refusing to silently restart from scratch")
+                latest = verified
+            if latest is not None:
+                meta = ckpt.load_metadata(ckpt_dir, latest)
+                if meta.get("kind") != "stream-epoch":
+                    raise ValueError(f"{ckpt_dir}: step {latest} is not a "
+                                     "streaming checkpoint")
+                # the cursor is only meaningful against the same shuffle and
+                # the same chunking — a silent mismatch would train some rows
+                # twice and others never, so refuse instead
+                if meta["seed"] != seed:
+                    raise ValueError(
+                        f"{ckpt_dir}: checkpoint was written with seed="
+                        f"{meta['seed']}, resume called with seed={seed}")
+                if meta["n_chunks"] != n_chunks:
+                    raise ValueError(
+                        f"{ckpt_dir}: checkpoint cursor is against "
+                        f"{meta['n_chunks']} chunks, source now has "
+                        f"{n_chunks} — re-chunked data cannot resume "
+                        "mid-epoch")
+                tree = ckpt.load(ckpt_dir, latest,
+                                 _ckpt_template(state, batch_size, dim))
+                state = tree["state"]
+                start_epoch, start_chunk = meta["epoch"], meta["next_chunk"]
+                resume_key = tree["epoch_key"]    # the interrupted epoch's key
+                cn = int(tree["carry_n"])
+                carry = (np.asarray(tree["carry_x"])[:cn],
+                         np.asarray(tree["carry_y"])[:cn])
+                if start_chunk >= n_chunks:   # checkpoint at an epoch end
+                    start_epoch, start_chunk, carry = start_epoch + 1, 0, None
+                    resume_key = None
 
-    budget_left = max_chunks
-    base_key = jax.random.PRNGKey(seed)
-    for epoch in range(start_epoch, epochs):
-        # the resumed epoch continues under its checkpointed RNG key (equal,
-        # by the seed guard above, to the rederived one); later epochs fold
-        epoch_key = (resume_key if epoch == start_epoch and
-                     resume_key is not None
-                     else jax.random.fold_in(base_key, epoch))
+        budget_left = max_chunks
+        base_key = jax.random.PRNGKey(seed)
+        for epoch in range(start_epoch, epochs):
+            # the resumed epoch continues under its checkpointed RNG key
+            # (equal, by the seed guard above, to the rederived one); later
+            # epochs fold
+            epoch_key = (resume_key if epoch == start_epoch and
+                         resume_key is not None
+                         else jax.random.fold_in(base_key, epoch))
 
-        def save(st, pos, cr, *, _epoch=epoch, _key=epoch_key):
-            done = pos + 1
-            if (publish is not None and publish_every
-                    and done % publish_every == 0):
-                publish(st)
-            if not (ckpt_dir and ckpt_every and done % ckpt_every == 0):
-                return
-            px, py, cn = _pad_carry(cr, batch_size, dim)
-            ckpt.save(ckpt_dir, _epoch * n_chunks + done,
-                      {"state": st, "epoch_key": _key, "carry_x": px,
-                       "carry_y": py, "carry_n": cn},
-                      keep_last=keep_last,
-                      metadata={"kind": "stream-epoch", "epoch": _epoch,
-                                "next_chunk": done, "n_chunks": n_chunks,
-                                "seed": seed})
+            def save(st, pos, cr, *, _epoch=epoch, _key=epoch_key):
+                done = pos + 1
+                if (publish is not None and publish_every
+                        and done % publish_every == 0):
+                    with obs.span("publish"):
+                        publish(st)
+                if not (ckpt_dir and ckpt_every
+                        and done % ckpt_every == 0):
+                    return
+                px, py, cn = _pad_carry(cr, batch_size, dim)
+                ckpt.save(ckpt_dir, _epoch * n_chunks + done,
+                          {"state": st, "epoch_key": _key, "carry_x": px,
+                           "carry_y": py, "carry_n": cn},
+                          keep_last=keep_last,
+                          metadata={"kind": "stream-epoch",
+                                    "epoch": _epoch, "next_chunk": done,
+                                    "n_chunks": n_chunks, "seed": seed})
 
-        state, next_chunk, carry, ran = _stream_epoch(
-            chunk_fn, state, source, batch_size=batch_size, key=epoch_key,
-            start_chunk=start_chunk, carry=carry, on_chunk=save,
-            max_chunks=budget_left, prefetch=prefetch, stage=stage,
-            retry=retry, report=report, skip_chunks=skip_chunks, guard=guard)
-        if budget_left is not None:
-            budget_left -= ran
-        if next_chunk < n_chunks:             # cut short by max_chunks
-            if publish is not None:
-                publish(state)
-            return state
-        jax.block_until_ready(state.alpha)    # sync only at epoch end
-        start_chunk, carry = 0, None          # sub-batch remainder dropped
-    if publish is not None:
-        publish(state)                        # the final model always lands
-    return state
+            state, next_chunk, carry, ran = _stream_epoch(
+                chunk_fn, state, source, batch_size=batch_size,
+                key=epoch_key, start_chunk=start_chunk, carry=carry,
+                on_chunk=save, max_chunks=budget_left, prefetch=prefetch,
+                stage=stage, retry=retry, report=report,
+                skip_chunks=skip_chunks, guard=guard, epoch=epoch)
+            if budget_left is not None:
+                budget_left -= ran
+            if next_chunk < n_chunks:         # cut short by max_chunks
+                if publish is not None:
+                    with obs.span("publish"):
+                        publish(state)
+                return state
+            jax.block_until_ready(state.alpha)  # sync only at epoch end
+            start_chunk, carry = 0, None  # sub-batch remainder dropped
+        if publish is not None:
+            with obs.span("publish"):
+                publish(state)                # the final model always lands
+        return state
 
 
 def _make_publish(bank, gamma, bank_dtype):

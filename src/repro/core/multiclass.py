@@ -274,7 +274,8 @@ def train_chunk_multiclass(cfg: MulticlassSVMConfig, table, state: SVMState,
         return train_step_multiclass(cfg, table, st, xb,
                                      yb.astype(jnp.int32), impl=impl), ()
 
-    state, _ = jax.lax.scan(body, state, (xc, yc))
+    with jax.named_scope("train_chunk_multiclass"):
+        state, _ = jax.lax.scan(body, state, (xc, yc))
     return state
 
 

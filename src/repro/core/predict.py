@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .bsgd import SVMState
 from ..kernels import ops as kops
 
@@ -127,10 +128,11 @@ def predict_labels(model: ServeModel, x, *, impl: str = "auto"):
     Multiclass models return (n,) int32 class ids; binary models return the
     (n,) float32 ±1 signs of ``bsgd.predict``.
     """
-    scores = serve_scores(model, x, impl=impl)
-    if model.binary:
-        return jnp.sign(scores[0]).astype(jnp.float32)
-    return jnp.argmax(scores, axis=0).astype(jnp.int32)
+    with jax.named_scope("predict_labels"):
+        scores = serve_scores(model, x, impl=impl)
+        if model.binary:
+            return jnp.sign(scores[0]).astype(jnp.float32)
+        return jnp.argmax(scores, axis=0).astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("k", "impl"))
@@ -430,6 +432,13 @@ class ModelBank:
             return self._slot
 
 
+# a request's life in the queue, and a dispatcher round, stage by stage
+_REQUEST = obs.Chain(("serve.wait", "serve.inflight", "serve.handoff"),
+                     ("ticket",))
+_LAUNCH = obs.Chain(("serve.assemble", "serve.launch"), ("rows", "bucket"))
+_RESOLVE = obs.Chain(("serve.sync", "serve.scatter"))
+
+
 class AsyncBatchQueue:
     """Continuous batching: a dispatcher thread owns the device, submitters
     never compute.
@@ -480,6 +489,16 @@ class AsyncBatchQueue:
     ``ServeDeadline``.  ``take``/``drain`` timeouts raise ``ServeTimeout``
     naming the ticket and the in-flight depth.  All three are typed results,
     never hangs — a supervisor can catch and retry/degrade.
+
+    Spans (``repro.obs``), all under one root per queue: per request, from
+    ``take``, ``serve.wait`` (submit to the launch carrying its last rows),
+    ``serve.inflight`` (that launch to its labels scattered) and
+    ``serve.handoff`` (scattered to ``take`` returning), each with its
+    ``ticket``; on the dispatcher thread ``serve.idle``, ``serve.assemble``
+    and ``serve.launch`` (``rows``, ``bucket``), ``serve.compile`` on an
+    executable-cache miss, ``serve.sync`` and ``serve.scatter``.  The
+    request and round stages are ``obs.Chain`` records, which cost the
+    queue a fraction of what a span each would.  ``warmup`` records none.
     """
 
     def __init__(self, model: ServeModel | ModelBank, *, max_batch: int = 256,
@@ -503,11 +522,16 @@ class AsyncBatchQueue:
         self._predict_fn = predict_fn
         self._compiled: dict = {}     # (bucket, bank signature) -> executable
         self._cv = threading.Condition()
-        self._pending: deque = deque()  # (ticket, rows, row_offset, deadline)
+        # (ticket, rows, row_offset, deadline, submit ns)
+        self._pending: deque = deque()
         self._pending_rows = 0
         self._need: dict[int, int] = {}
         self._parts: dict[int, list] = {}
         self._done: dict[int, np.ndarray] = {}
+        # ticket -> (submit, last launch, scattered) ns, for its spans
+        self._times: dict[int, tuple] = {}
+        self._root = obs.new_root()
+        self._root_id = self._root.root_id
         self._dead: dict[int, str] = {}   # ticket -> shed reason
         self._next_ticket = 0
         self._unresolved = 0
@@ -533,6 +557,7 @@ class AsyncBatchQueue:
         labels.  Raises ``QueueFull`` when ``max_pending`` rows are already
         buffered (bounded-pending load shedding).
         """
+        t_submit = obs.now_ns()
         x = np.asarray(x)
         try:
             dim = self._current()[1].sv_x.shape[-1]
@@ -556,11 +581,12 @@ class AsyncBatchQueue:
             self._parts[ticket] = []
             if x.shape[0] == 0:
                 self._done[ticket] = np.zeros((0,), self._label_dtype())
+                self._times[ticket] = (t_submit,) * 3
                 self._need.pop(ticket)
                 self._parts.pop(ticket)
             else:
                 self._unresolved += 1
-                self._pending.append((ticket, x, 0, dl))
+                self._pending.append((ticket, x, 0, dl, t_submit))
                 self._pending_rows += x.shape[0]
                 # only wake the dispatcher when the gate is actually open
                 # (full batch, or a waiter already blocked) — an
@@ -590,7 +616,11 @@ class AsyncBatchQueue:
             if ticket in self._dead:
                 raise ServeDeadline(
                     f"ticket {ticket} shed: {self._dead.pop(ticket)}")
-            return self._done.pop(ticket)
+            labels = self._done.pop(ticket)
+            t_submit, t_launch, t_scatter = self._times.pop(ticket)
+        _REQUEST.record((t_submit, t_launch, t_scatter, obs.now_ns()),
+                        self._root_id, ticket)
+        return labels
 
     def drain(self, timeout: float | None = None) -> None:
         """Block until every submitted row is scored, resolved or shed."""
@@ -653,8 +683,11 @@ class AsyncBatchQueue:
         version, model = self._current()
         dim = model.sv_x.shape[-1]
         for b in self.buckets:
-            jax.block_until_ready(
-                self._score(model, np.zeros((b, dim), dtype), b))
+            xb = np.zeros((b, dim), dtype)
+            sig = self._sig(model, xb, b)
+            if self._predict_fn is None and sig not in self._compiled:
+                self._compile(model, xb, sig)   # here, not as serve.compile
+            jax.block_until_ready(self._score(model, xb, b))
 
     # -- dispatcher side -----------------------------------------------------
 
@@ -674,16 +707,25 @@ class AsyncBatchQueue:
             return self._bank.current()
         return None, self.model
 
+    @staticmethod
+    def _sig(model: ServeModel, xb: np.ndarray, bucket: int) -> tuple:
+        return (bucket, str(xb.dtype), model.sv_x.shape,
+                str(model.sv_x.dtype), model.binary)
+
+    def _compile(self, model: ServeModel, xb: np.ndarray, sig: tuple):
+        fn = predict_labels.lower(model, xb, impl=self._impl).compile()
+        self._compiled[sig] = fn
+        return fn
+
     def _score(self, model: ServeModel, xb: np.ndarray, bucket: int):
         """One microbatch launch (async dispatch — no host sync here)."""
         if self._predict_fn is not None:
             return self._predict_fn(xb)
-        sig = (bucket, str(xb.dtype), model.sv_x.shape,
-               str(model.sv_x.dtype), model.binary)
+        sig = self._sig(model, xb, bucket)
         fn = self._compiled.get(sig)
         if fn is None:
-            fn = predict_labels.lower(model, xb, impl=self._impl).compile()
-            self._compiled[sig] = fn
+            with obs.span("serve.compile"):
+                fn = self._compile(model, xb, sig)
         return fn(model, xb)
 
     def _earliest_deadline_locked(self) -> float | None:
@@ -700,9 +742,10 @@ class AsyncBatchQueue:
         now = time.monotonic()
         kept: deque = deque()
         shed = False
-        for ticket, x, off, dl in self._pending:
+        for entry in self._pending:
+            ticket, x, _, dl, _ = entry
             if dl is None or now < dl:
-                kept.append((ticket, x, off, dl))
+                kept.append(entry)
                 continue
             shed = True
             self._pending_rows -= x.shape[0]
@@ -722,12 +765,13 @@ class AsyncBatchQueue:
         n_real = min(self._pending_rows, self.max_batch)
         rows, slices, need = [], [], n_real
         while need:
-            ticket, x, off, dl = self._pending.popleft()
+            ticket, x, off, dl, t_submit = self._pending.popleft()
             take = min(need, x.shape[0])
             rows.append(x[:take])
-            slices.append((ticket, off, take))
+            slices.append((ticket, off, take, t_submit))
             if take < x.shape[0]:
-                self._pending.appendleft((ticket, x[take:], off + take, dl))
+                self._pending.appendleft(
+                    (ticket, x[take:], off + take, dl, t_submit))
             need -= take
         self._pending_rows -= n_real
         return rows, slices, n_real
@@ -735,90 +779,118 @@ class AsyncBatchQueue:
     def _launch(self, rows, slices, n_real):
         """Assemble + dispatch one microbatch (outside the lock)."""
         pad_to = pad_bucket(n_real, self.buckets)
-        xb = np.zeros((pad_to, rows[0].shape[1]), rows[0].dtype)
-        pos = 0
-        for r in rows:
-            xb[pos:pos + r.shape[0]] = r
-            pos += r.shape[0]
         # a fixed model needs no bank read in the hot loop
         version, model = ((None, self.model) if self._bank is None
                           else self._bank.current())
-        t0 = time.perf_counter()
-        labels = self._score(model, xb, pad_to)
-        return labels, slices, n_real, pad_to, version, t0
+        # each stage's clock readings lie inside its annotation, so that an
+        # annotation's own cost (its first on a thread allocates) is outside
+        with obs.annotate("serve.assemble", rows=n_real, bucket=pad_to):
+            t_assemble = obs.now_ns()
+            xb = np.zeros((pad_to, rows[0].shape[1]), rows[0].dtype)
+            pos = 0
+            for r in rows:
+                xb[pos:pos + r.shape[0]] = r
+                pos += r.shape[0]
+        with obs.annotate("serve.launch"):
+            t_launch = obs.now_ns()
+            t0 = time.perf_counter()
+            labels = self._score(model, xb, pad_to)
+            t_end = obs.now_ns()
+        _LAUNCH.record((t_assemble, t_launch, t_end), self._root_id, n_real,
+                       pad_to)
+        return labels, slices, n_real, pad_to, version, t0, t_launch
 
     def _resolve(self, inflight) -> None:
         """Sync one launch, scatter its labels, resolve finished tickets."""
-        labels, slices, n_real, pad_to, version, t0 = inflight
-        labels = np.asarray(labels)               # blocks until scored
+        labels, slices, n_real, pad_to, version, t0, t_launch = inflight
+        with obs.annotate("serve.sync"):
+            t_sync = obs.now_ns()
+            labels = np.asarray(labels)           # blocks until scored
         lat = time.perf_counter() - t0
-        parts_by_slice = []                       # slice outside the lock
-        pos = 0
-        for ticket, off, take in slices:
-            parts_by_slice.append(labels[pos:pos + take])
-            pos += take
-        with self._cv:
-            self.latencies_s.append(lat)
-            st = self.stats
-            st["rows"] += n_real
-            st["microbatches"] += 1
-            st["padded_rows"] += pad_to - n_real
-            st["bucket_counts"][pad_to] = \
-                st["bucket_counts"].get(pad_to, 0) + 1
-            st["bucket_real_rows"][pad_to] = \
-                st["bucket_real_rows"].get(pad_to, 0) + n_real
-            if version is not None:
-                st["versions"][version] = st["versions"].get(version, 0) + 1
-            for (ticket, off, take), part in zip(slices, parts_by_slice):
-                if ticket in self._dead:
-                    continue   # shed mid-flight — drop its labels
-                need = self._need[ticket]
-                if off == 0 and take == need:     # single-part fast path
-                    self._done[ticket] = part
-                    self._need.pop(ticket)
-                    self._parts.pop(ticket)
-                    self._unresolved -= 1
-                    continue
-                parts = self._parts[ticket]
-                parts.append((off, part))
-                if sum(p[1].shape[0] for p in parts) == need:
-                    parts.sort(key=lambda p: p[0])
-                    self._done[ticket] = np.concatenate([p[1] for p in parts])
-                    self._need.pop(ticket)
-                    self._parts.pop(ticket)
-                    self._unresolved -= 1
-            self._cv.notify_all()
+        with obs.annotate("serve.scatter"):
+            t_resolve = obs.now_ns()
+            parts_by_slice = []                   # slice outside the lock
+            pos = 0
+            for _, _, take, _ in slices:
+                parts_by_slice.append(labels[pos:pos + take])
+                pos += take
+            with self._cv:
+                t_scatter = obs.now_ns()
+                self.latencies_s.append(lat)
+                st = self.stats
+                st["rows"] += n_real
+                st["microbatches"] += 1
+                st["padded_rows"] += pad_to - n_real
+                st["bucket_counts"][pad_to] = \
+                    st["bucket_counts"].get(pad_to, 0) + 1
+                st["bucket_real_rows"][pad_to] = \
+                    st["bucket_real_rows"].get(pad_to, 0) + n_real
+                if version is not None:
+                    st["versions"][version] = \
+                        st["versions"].get(version, 0) + 1
+                for (ticket, off, take, t_submit), part in zip(slices,
+                                                               parts_by_slice):
+                    if ticket in self._dead:
+                        continue   # shed mid-flight — drop its labels
+                    need = self._need[ticket]
+                    if off == 0 and take == need:     # single-part fast path
+                        self._done[ticket] = part
+                        self._times[ticket] = (t_submit, t_launch, t_scatter)
+                        self._need.pop(ticket)
+                        self._parts.pop(ticket)
+                        self._unresolved -= 1
+                        continue
+                    parts = self._parts[ticket]
+                    parts.append((off, part))
+                    if sum(p[1].shape[0] for p in parts) == need:
+                        parts.sort(key=lambda p: p[0])
+                        self._done[ticket] = np.concatenate(
+                            [p[1] for p in parts])
+                        self._times[ticket] = (t_submit, t_launch, t_scatter)
+                        self._need.pop(ticket)
+                        self._parts.pop(ticket)
+                        self._unresolved -= 1
+                self._cv.notify_all()
+            t_end = obs.now_ns()
+        _RESOLVE.record((t_sync, t_resolve, t_end), self._root_id)
 
     def _dispatch_loop(self) -> None:
         inflight = None
+
+        # dispatchable = a full batch pends, or someone is blocked on the
+        # result (take/drain/close) — partial batches otherwise coalesce
+        def dispatchable():
+            return self._pending_rows and (
+                self._pending_rows >= self.max_batch
+                or self._waiters or self._stop)
+
+        def idle():
+            return (not dispatchable() and not self._stop
+                    and inflight is None)
+
         try:
-            while True:
-                batch = None
-                with self._cv:
-                    # dispatchable = a full batch pends, or someone is
-                    # blocked on the result (take/drain/close) — partial
-                    # batches otherwise keep coalescing
-                    def dispatchable():
-                        return self._pending_rows and (
-                            self._pending_rows >= self.max_batch
-                            or self._waiters or self._stop)
-                    while (not dispatchable() and not self._stop
-                           and inflight is None):
-                        self._cv.wait()
-                    if (self._stop and not self._pending_rows
-                            and inflight is None):
-                        return
-                    if dispatchable():
-                        batch = self._pop_rows_locked()
-                # dispatch the NEXT microbatch before syncing the previous:
-                # the device is never idle while the host scatters labels
-                # (a purge can shed every pending row — then there is
-                # nothing to launch)
-                launched = (self._launch(*batch)
-                            if batch is not None and batch[2] else None)
-                if inflight is not None:
-                    self._resolve(inflight)
-                inflight = launched
+            with obs.attach(self._root):
+                while True:
+                    batch = None
+                    with self._cv:
+                        if idle():
+                            with obs.span("serve.idle"):
+                                while idle():
+                                    self._cv.wait()
+                        if (self._stop and not self._pending_rows
+                                and inflight is None):
+                            return
+                        if dispatchable():
+                            batch = self._pop_rows_locked()
+                    # dispatch the NEXT microbatch before syncing the previous:
+                    # the device is never idle while the host scatters labels
+                    # (a purge can shed every pending row — then there is
+                    # nothing to launch)
+                    launched = (self._launch(*batch)
+                                if batch is not None and batch[2] else None)
+                    if inflight is not None:
+                        self._resolve(inflight)
+                    inflight = launched
         except BaseException as e:  # noqa: BLE001 — surfaced to callers
             with self._cv:
                 self._error = e

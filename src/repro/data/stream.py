@@ -35,6 +35,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
+from .. import obs
 from .libsvm import parse_libsvm
 
 
@@ -467,12 +468,14 @@ def iter_epoch(source: ChunkSource, key=None, *, start_chunk: int = 0,
             if cid in skip:
                 continue
             try:
-                if retry is not None and not worker_retries:
-                    x, y = load_chunk_with_retry(
-                        source, cid, retry, report=report,
-                        expected_rows=source.chunk_lens[cid], dim=source.dim)
-                else:
-                    x, y = source.load(cid)
+                with obs.span("stream.load"):
+                    if retry is not None and not worker_retries:
+                        x, y = load_chunk_with_retry(
+                            source, cid, retry, report=report,
+                            expected_rows=source.chunk_lens[cid],
+                            dim=source.dim)
+                    else:
+                        x, y = source.load(cid)
             except Exception as e:  # noqa: BLE001 — quarantine-only filter
                 if not (resilient and isinstance(e, ChunkQuarantined)):
                     raise

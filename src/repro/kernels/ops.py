@@ -113,10 +113,11 @@ def class_scores(x, sv_x, alpha, gamma, *, impl: str = "auto"):
     ``ref.class_scores`` (C sequential kernel calls).
     """
     c, slots, d = sv_x.shape
-    k = rbf_matrix(x, sv_x.reshape(c * slots, d), gamma, impl=impl)
-    k = k.reshape(x.shape[0], c, slots)
-    return jnp.einsum("ncs,cs->cn", k.astype(alpha.dtype), alpha,
-                      precision=jax.lax.Precision.HIGHEST)
+    with jax.named_scope("class_scores"):
+        k = rbf_matrix(x, sv_x.reshape(c * slots, d), gamma, impl=impl)
+        k = k.reshape(x.shape[0], c, slots)
+        return jnp.einsum("ncs,cs->cn", k.astype(alpha.dtype), alpha,
+                          precision=jax.lax.Precision.HIGHEST)
 
 
 # --------------------------------------------------------------------------
@@ -315,12 +316,13 @@ def train_step(sv_x, alpha, kmat, count, step, n_inserts, n_merges, xb, yb,
             lambda_=lambda_, gamma=gamma, batch_size=batch_size,
             maintenance=maintenance, merge_batch=merge_batch, unroll=unroll)
     _, s, d = sv_x.shape
-    sv_p = _pad_to_lane(sv_x, (1, 2))
-    al_p = _pad_to_lane(alpha, 1)
-    km_p = _pad_to_lane(kmat, (1, 2))
-    xb_p = _pad_to_lane(xb, (0, 1))
-    kbb_p = _pad_to_lane(k_bb, (0, 1))
-    yb_p = _pad_to_lane(yb, 1)
+    with jax.named_scope("train_step.pad"):
+        sv_p = _pad_to_lane(sv_x, (1, 2))
+        al_p = _pad_to_lane(alpha, 1)
+        km_p = _pad_to_lane(kmat, (1, 2))
+        xb_p = _pad_to_lane(xb, (0, 1))
+        kbb_p = _pad_to_lane(k_bb, (0, 1))
+        yb_p = _pad_to_lane(yb, 1)
     sv_n, al_n, km_n, cnt_n, nins_n, nmrg_n = \
         train_step_kernel.train_step_pallas(
             sv_p, al_p[:, None, :], km_p, count, step, n_inserts, n_merges,
@@ -329,5 +331,6 @@ def train_step(sv_x, alpha, kmat, count, step, n_inserts, n_merges, xb, yb,
             batch_size=batch_size, rounds=batch_size,
             maintenance=maintenance, merge_batch=merge_batch,
             block_s=block_s, interpret=(impl == "pallas_interpret"))
-    return (sv_n[:, :s, :d], al_n[:, 0, :s], km_n[:, :s, :s], cnt_n,
-            step + 1, nins_n, nmrg_n)
+    with jax.named_scope("train_step.unpad"):
+        return (sv_n[:, :s, :d], al_n[:, 0, :s], km_n[:, :s, :s], cnt_n,
+                step + 1, nins_n, nmrg_n)
