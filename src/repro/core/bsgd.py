@@ -373,6 +373,60 @@ def train_step(cfg: BSGDConfig, table, state: SVMState, xb, yb, *,
                                 impl=impl)
 
 
+def carries_padded(cfg: BSGDConfig, impl: str) -> bool:
+    """Whether a scan of ``cfg``'s steps runs the fused Pallas kernel and so
+    carries the state in its lane-padded layout (``scan_fused``).  The
+    ``ref`` path and the composed engine carry the state as it is."""
+    return cfg.step_engine == "pallas" and kops.runs_pallas(impl)
+
+
+def scan_fused(cfg: BSGDConfig, table, state: SVMState, xs, batch, *,
+               impl: str) -> SVMState:
+    """``lax.scan`` of fused Pallas steps with the lane-padded state in the
+    carry: the real state of a scan of ``kops.train_step``, bitwise.
+
+    ``state`` is stacked (every leaf has a leading (C,) axis); ``batch(x)``
+    maps one scanned element of ``xs`` to the minibatch ``xb`` (batch, d)
+    and its one-vs-rest targets (C, batch).  The state is padded once before
+    the loop (named scope ``train_chunk.pad``) and sliced once after it
+    (``train_chunk.unpad``); in between the kernel updates the carried
+    blocks in place, so no step copies the state.
+    """
+    _, s, d = state.sv_x.shape
+
+    def body(carry, x):
+        xb, y_ovr = batch(x)
+        k_bb = kops.rbf_matrix(xb, xb, cfg.gamma, impl=impl)
+        return kops.train_step_padded(
+            *carry, xb, y_ovr, k_bb, table, budget=cfg.budget,
+            lambda_=cfg.lambda_, gamma=cfg.gamma,
+            batch_size=cfg.batch_size, maintenance=cfg.maintenance,
+            merge_batch=cfg.merge_batch, impl=impl), ()
+
+    with jax.named_scope("train_chunk.pad"):
+        padded = kops.pad_fused_state(state.sv_x, state.alpha, state.kmat)
+    carry = (*padded, state.count, state.step, state.n_inserts,
+             state.n_merges)
+    (sv, al, km, cnt, step, nin, nmg), _ = jax.lax.scan(body, carry, xs)
+    with jax.named_scope("train_chunk.unpad"):
+        sv, al, km = kops.unpad_fused_state(sv, al, km, s, d)
+    return SVMState(sv_x=sv, alpha=al, count=cnt, step=step, n_inserts=nin,
+                    n_merges=nmg, kmat=km)
+
+
+def scan_fused_binary(cfg: BSGDConfig, table, state: SVMState, xs, batch, *,
+                      impl: str) -> SVMState:
+    """``scan_fused`` for a binary state: lifted to C = 1 as ``train_step``
+    lifts it; ``batch(x)`` gives ``(xb, yb)`` with yb (batch,)."""
+    def lifted(x):
+        xb, yb = batch(x)
+        return xb, yb[None]
+
+    out = scan_fused(cfg, table, jax.tree.map(lambda a: a[None], state), xs,
+                     lifted, impl=impl)
+    return jax.tree.map(lambda a: a[0], out)
+
+
 @partial(jax.jit, static_argnames=("cfg", "impl"))
 def train_epoch(cfg: BSGDConfig, table, state: SVMState, x, y, perm, *,
                 impl: str = "auto") -> SVMState:
@@ -390,6 +444,12 @@ def train_epoch(cfg: BSGDConfig, table, state: SVMState, x, y, perm, *,
     n = perm.shape[0]
     steps = n // cfg.batch_size
     order = perm[: steps * cfg.batch_size].reshape(steps, cfg.batch_size)
+
+    if carries_padded(cfg, impl):
+        return scan_fused_binary(
+            cfg, table, state, order,
+            lambda idx: (jnp.take(x, idx, axis=0), jnp.take(y, idx, axis=0)),
+            impl=impl)
 
     def scan_body(st, batch_idx):
         xb = jnp.take(x, batch_idx, axis=0)
@@ -442,13 +502,17 @@ def train_chunk(cfg: BSGDConfig, table, state: SVMState, xc, yc, *,
     shuffled and reshaped into minibatches on the host.  The scan body is the
     same traced ``train_step`` as the in-memory ``train_epoch``, so the hot
     path is identical; donating ``state`` lets XLA update the budgeted model
-    in place while chunks stream through.
+    in place while chunks stream through.  The fused Pallas step carries
+    its lane-padded state through the scan (``scan_fused``).
     """
     def body(st, xy):
         xb, yb = xy
         return train_step(cfg, table, st, xb, yb, impl=impl), ()
 
     with jax.named_scope("train_chunk"):
+        if carries_padded(cfg, impl):
+            return scan_fused_binary(cfg, table, state, (xc, yc),
+                                     lambda xy: xy, impl=impl)
         state, _ = jax.lax.scan(body, state, (xc, yc))
     return state
 

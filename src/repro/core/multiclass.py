@@ -37,8 +37,9 @@ import jax.numpy as jnp
 
 from . import budget as budget_mod
 from .bsgd import (BSGDConfig, SVMState, _device_stage, _fit_stream,
-                   _make_guard, _make_publish, _stream_epoch, init_state,
-                   insert_from_rows, train_step_from_rows)
+                   _make_guard, _make_publish, _stream_epoch, carries_padded,
+                   init_state, insert_from_rows, scan_fused,
+                   train_step_from_rows)
 from ..kernels import ops as kops
 
 
@@ -168,8 +169,13 @@ def train_step_multiclass(cfg: MulticlassSVMConfig, table, state: SVMState,
     classes fold onto the kernel grid and the cache stays VMEM-resident
     across all three phases (DESIGN.md §12).
     """
-    y_ovr = ovr_targets(yb, cfg.n_classes, dtype=jnp.dtype(cfg.binary.dtype))
-    return train_step_ovr(cfg.binary, table, state, xb, y_ovr, impl=impl)
+    return train_step_ovr(cfg.binary, table, state, xb, _targets(cfg, yb),
+                          impl=impl)
+
+
+def _targets(cfg: MulticlassSVMConfig, yb):
+    """Class ids (batch,) -> the step's one-vs-rest targets (C, batch)."""
+    return ovr_targets(yb, cfg.n_classes, dtype=jnp.dtype(cfg.binary.dtype))
 
 
 def train_step_ovr(b: BSGDConfig, table, state: SVMState, xb, y_ovr, *,
@@ -229,6 +235,12 @@ def train_epoch_multiclass(cfg: MulticlassSVMConfig, table, state: SVMState,
     bs = cfg.binary.batch_size
     steps = perm.shape[0] // bs
     order = perm[: steps * bs].reshape(steps, bs)
+    if carries_padded(cfg.binary, impl):
+        return scan_fused(
+            cfg.binary, table, state, order,
+            lambda idx: (jnp.take(x, idx, axis=0),
+                         _targets(cfg, jnp.take(y, idx, axis=0))),
+            impl=impl)
 
     def scan_body(st, batch_idx):
         xb = jnp.take(x, batch_idx, axis=0)
@@ -268,13 +280,19 @@ def train_chunk_multiclass(cfg: MulticlassSVMConfig, table, state: SVMState,
                            xc, yc, *, impl: str = "auto") -> SVMState:
     """One resident chunk of the one-vs-rest engine as a single donated-state
     program; ``xc: (steps, batch, dim)``, ``yc: (steps, batch)`` class ids
-    (cf. ``bsgd.train_chunk``)."""
+    (cf. ``bsgd.train_chunk``).  The fused Pallas step carries its
+    lane-padded state through the scan (``bsgd.scan_fused``)."""
     def body(st, xy):
         xb, yb = xy
         return train_step_multiclass(cfg, table, st, xb,
                                      yb.astype(jnp.int32), impl=impl), ()
 
     with jax.named_scope("train_chunk_multiclass"):
+        if carries_padded(cfg.binary, impl):
+            return scan_fused(
+                cfg.binary, table, state, (xc, yc),
+                lambda xy: (xy[0], _targets(cfg, xy[1].astype(jnp.int32))),
+                impl=impl)
         state, _ = jax.lax.scan(body, state, (xc, yc))
     return state
 

@@ -38,6 +38,12 @@ def _resolve(impl: str) -> str:
     return impl
 
 
+def runs_pallas(impl: str) -> bool:
+    """Whether ``impl`` resolves to a Pallas kernel (compiled or
+    interpreted) rather than the ``ref`` oracle."""
+    return _resolve(impl) != "ref"
+
+
 def _pad_to(x, axis: int, multiple: int, value=0.0):
     size = x.shape[axis]
     pad = (-size) % multiple
@@ -285,6 +291,62 @@ def multi_merge_scores(alpha, kappa_rows, valid, a_min, table, *,
 # --------------------------------------------------------------------------
 # Fused train step (margin + insert + event rounds, one launch chain)
 # --------------------------------------------------------------------------
+def pad_fused_state(sv_x, alpha, kmat):
+    """The fused step's stacked state in the kernel's lane-padded layout.
+
+    sv_x: (C, s, d); alpha: (C, s); kmat: (C, s, s) -> (C, S, D), (C, 1, S),
+    (C, S, S) with S, D the multiples of 128 at or above s, d; the pad
+    region is zero.  ``train_step_padded`` keeps it finite and never lets it
+    reach the real region, so a scan may carry these blocks from step to
+    step and slice once at the end (``unpad_fused_state``).
+    """
+    return (_pad_to_lane(sv_x, (1, 2)), _pad_to_lane(alpha, 1)[:, None, :],
+            _pad_to_lane(kmat.astype(jnp.float32), (1, 2)))
+
+
+def unpad_fused_state(sv_p, al_p, km_p, s: int, d: int):
+    """Inverse of ``pad_fused_state``: the real (C, s, d), (C, s), (C, s, s)
+    region, whatever the pad region holds."""
+    return sv_p[:, :s, :d], al_p[:, 0, :s], km_p[:, :s, :s]
+
+
+@partial(jax.jit, static_argnames=("budget", "lambda_", "gamma", "batch_size",
+                                   "maintenance", "merge_batch", "impl",
+                                   "block_s"))
+def train_step_padded(sv_p, al_p, km_p, count, step, n_inserts, n_merges, xb,
+                      yb, k_bb, table, *, budget: int, lambda_: float,
+                      gamma: float, batch_size: int, maintenance: str = "merge",
+                      merge_batch: int = 4, impl: str = "auto",
+                      block_s: int = 256):
+    """``train_step`` on state already in the lane-padded layout of
+    ``pad_fused_state``: returns the updated padded blocks and counters.
+
+    Only the minibatch (``xb``, ``yb``, ``k_bb``, a few KB) is padded here.
+    The kernel updates the state blocks in place, so a ``lax.scan`` that
+    carries them copies nothing per step.  The pad region stays as the
+    kernel leaves it: zero bank lanes and slots, zero alpha, and cache rows
+    and columns of values in [0, 1] that nothing in the real region reads.
+    ``impl`` must resolve to ``"pallas"`` or ``"pallas_interpret"``.
+    """
+    impl = _resolve(impl)
+    if impl == "ref":
+        raise ValueError("train_step_padded runs the Pallas kernel; the ref "
+                         "path takes unpadded state (train_step)")
+    with jax.named_scope("train_step.pad_batch"):
+        xb_p = _pad_to_lane(xb, (0, 1))
+        kbb_p = _pad_to_lane(k_bb, (0, 1))
+        yb_p = _pad_to_lane(yb, 1)
+    sv_n, al_n, km_n, cnt_n, nins_n, nmrg_n = \
+        train_step_kernel.train_step_pallas(
+            sv_p, al_p, km_p, count, step, n_inserts, n_merges, xb_p,
+            yb_p[:, None, :], kbb_p, table.h_table, table.wd_table,
+            budget=budget, lambda_=lambda_, gamma=gamma,
+            batch_size=batch_size, rounds=batch_size,
+            maintenance=maintenance, merge_batch=merge_batch,
+            block_s=block_s, interpret=(impl == "pallas_interpret"))
+    return sv_n, al_n, km_n, cnt_n, step + 1, nins_n, nmrg_n
+
+
 @partial(jax.jit, static_argnames=("budget", "lambda_", "gamma", "batch_size",
                                    "maintenance", "merge_batch", "unroll",
                                    "impl", "block_s"))
@@ -307,6 +369,10 @@ def train_step(sv_x, alpha, kmat, count, step, n_inserts, n_merges, xb, yb,
     bounds the excess by ``batch_size``).  Returns the updated ``(sv_x,
     alpha, kmat, count, step, n_inserts, n_merges)``.  Oracle and CPU
     production path: ``ref.train_step_fused``.
+
+    The Pallas path pads the state, runs ``train_step_padded`` and slices
+    back: four copies of the whole state per call.  A scan of steps pads
+    once and carries the padded blocks instead (``core.bsgd.scan_fused``).
     """
     impl = _resolve(impl)
     if impl == "ref":
@@ -317,20 +383,11 @@ def train_step(sv_x, alpha, kmat, count, step, n_inserts, n_merges, xb, yb,
             maintenance=maintenance, merge_batch=merge_batch, unroll=unroll)
     _, s, d = sv_x.shape
     with jax.named_scope("train_step.pad"):
-        sv_p = _pad_to_lane(sv_x, (1, 2))
-        al_p = _pad_to_lane(alpha, 1)
-        km_p = _pad_to_lane(kmat, (1, 2))
-        xb_p = _pad_to_lane(xb, (0, 1))
-        kbb_p = _pad_to_lane(k_bb, (0, 1))
-        yb_p = _pad_to_lane(yb, 1)
-    sv_n, al_n, km_n, cnt_n, nins_n, nmrg_n = \
-        train_step_kernel.train_step_pallas(
-            sv_p, al_p[:, None, :], km_p, count, step, n_inserts, n_merges,
-            xb_p, yb_p[:, None, :], kbb_p, table.h_table, table.wd_table,
-            budget=budget, lambda_=lambda_, gamma=gamma,
-            batch_size=batch_size, rounds=batch_size,
-            maintenance=maintenance, merge_batch=merge_batch,
-            block_s=block_s, interpret=(impl == "pallas_interpret"))
+        padded = pad_fused_state(sv_x, alpha, kmat)
+    sv_n, al_n, km_n, *counters = train_step_padded(
+        *padded, count, step, n_inserts, n_merges, xb, yb, k_bb, table,
+        budget=budget, lambda_=lambda_, gamma=gamma, batch_size=batch_size,
+        maintenance=maintenance, merge_batch=merge_batch, impl=impl,
+        block_s=block_s)
     with jax.named_scope("train_step.unpad"):
-        return (sv_n[:, :s, :d], al_n[:, 0, :s], km_n[:, :s, :s], cnt_n,
-                step + 1, nins_n, nmrg_n)
+        return (*unpad_fused_state(sv_n, al_n, km_n, s, d), *counters)

@@ -325,8 +325,12 @@ def train_step_pallas(sv_x, alpha, kmat, count, step, n_inserts, n_merges,
     n_inserts / n_merges: (C,) int32, prefetched into SMEM; xb: (B, D)
     minibatch shared across the grid (rows >= ``batch_size`` are padding);
     yb: (C, 1, B) one-vs-rest targets; k_bb: (B, B) = k(xb, xb); tables:
-    (G, G).  S, D and B must be multiples of the tile sizes
-    (``ops.train_step`` pads).  Returns ``(sv_x, alpha, kmat, count,
+    (G, G).  S, D and B must be multiples of the tile sizes: the caller
+    pads (``ops.pad_fused_state`` once per chunk scan, or ``ops.train_step``
+    around one step; DESIGN.md §12).  Slots and lanes past the real ones
+    may hold whatever an earlier call left there: the kernel writes only
+    real bank rows, keeps alpha zero past the real slots, and reads pad
+    cache entries into pad entries only.  Returns ``(sv_x, alpha, kmat, count,
     n_inserts, n_merges)`` with the counters as (C,) int32 — the caller owns
     ``step + 1``.  The bank, alpha and cache outputs alias their inputs so
     the stacked state updates in place; class blocks are double-buffered

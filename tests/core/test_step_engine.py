@@ -12,7 +12,10 @@ The contracts pinned here (DESIGN.md §12):
     numbers compare like for like);
   * the BOGD-style ``maintenance="removal-project"`` strategy matches its
     closed form and stays loop-exact under the vmapped multi-class step;
-  * ``kernels.ops._pad_to_lane`` round-trips (pad then slice == identity).
+  * ``kernels.ops._pad_to_lane`` round-trips (pad then slice == identity);
+  * a chunk scan of the Pallas fused step carries the lane-padded state:
+    bitwise the real state of a scan of per-step ``ops.train_step``, a pad
+    region that stays finite, and no state pad or slice inside the loop.
 """
 import jax
 import jax.numpy as jnp
@@ -23,6 +26,7 @@ from repro.core import (BSGDConfig, MulticlassSVMConfig, accuracy, fit,
                         fit_multiclass, fit_multiclass_loop, kernel_cache)
 from repro.core.budget import _removal_all, _removal_project_all
 from repro.data import make_blobs_multiclass, make_two_moons, train_test_split
+from repro.kernels import ops
 from repro.kernels.ops import _pad_to_lane
 
 GAMMA = 0.5
@@ -44,6 +48,7 @@ def _fit_mc(cfg_b, n_classes, seed=0):
 # ints BITWISE, floats inside fp32 round-off — shared with the cross-solver
 # harness (tests/helpers/invariants.py)
 from helpers.invariants import assert_state_parity as _assert_state_parity
+from helpers.hlo import ops_on_shapes
 
 
 # --------------------------------------------------------------------------
@@ -156,6 +161,113 @@ def test_fused_step_parity_at_bench_cells(dim, budget, n_classes):
     st_f = make_step(cfg_f)(table, state, xb, yb)
     assert int(jnp.sum(st_c.n_merges)) > 0
     _assert_state_parity(st_c, st_f)
+
+
+# --------------------------------------------------------------------------
+# the chunk scan carries the fused step's lane-padded state
+# --------------------------------------------------------------------------
+CARRY_STEPS, CARRY_DIM, CARRY_BUDGET, CARRY_BATCH = 64, 6, 20, 8
+
+
+@pytest.mark.parametrize("strategy", ["merge", "multi-merge"])
+@pytest.mark.parametrize("n_classes", [1, 3])
+def test_chunk_carry_matches_per_step(n_classes, strategy):
+    """The chunk and epoch programs of the Pallas fused step carry the
+    lane-padded state: the real state comes out bitwise as from a scan of
+    per-step ``ops.train_step``, and the pad region stays finite after every
+    step.  Slots 28 and d 6 are not lane multiples; most steps merge."""
+    from repro.core import bsgd, multiclass
+    b = BSGDConfig(budget=CARRY_BUDGET, lambda_=1e-3, gamma=GAMMA,
+                   batch_size=CARRY_BATCH, method="lookup-wd",
+                   use_kernel_cache=True, maintenance=strategy,
+                   step_engine="pallas")
+    key = jax.random.PRNGKey(n_classes)
+    n = CARRY_STEPS * CARRY_BATCH
+    if n_classes == 1:
+        x, y = make_two_moons(key, n, noise=0.3, dim=CARRY_DIM)
+        cfg, state = b, bsgd.init_state(b, CARRY_DIM)
+        chunk, epoch, step = bsgd.train_chunk, bsgd.train_epoch, \
+            bsgd.train_step
+        targets = lambda yb: yb[None]
+    else:
+        x, y = make_blobs_multiclass(key, n, CARRY_DIM, n_classes=n_classes,
+                                     sep=1.0)
+        cfg = MulticlassSVMConfig(n_classes=n_classes, binary=b)
+        state = multiclass.init_multiclass_state(cfg, CARRY_DIM)
+        chunk, epoch, step = (multiclass.train_chunk_multiclass,
+                              multiclass.train_epoch_multiclass,
+                              multiclass.train_step_multiclass)
+        targets = lambda yb: multiclass.ovr_targets(yb, n_classes)
+    xc = x.reshape(CARRY_STEPS, CARRY_BATCH, CARRY_DIM)
+    yc = y.reshape(CARRY_STEPS, CARRY_BATCH)
+    table = cfg.table()
+    interp = "pallas_interpret"
+
+    want = jax.jit(lambda st: jax.lax.scan(
+        lambda s_, xy: (step(cfg, table, s_, *xy, impl=interp), ()),
+        st, (xc, yc))[0])(state)
+    assert int(jnp.sum(want.n_merges)) > (CARRY_STEPS // 2) * n_classes
+    got_chunk = chunk(cfg, table, jax.tree.map(jnp.array, state), xc, yc,
+                      impl=interp)
+    got_epoch = epoch(cfg, table, state, x, y, jnp.arange(n), impl=interp)
+    for got in (got_chunk, got_epoch):
+        for name, w, g in zip(want._fields, want, got):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                          err_msg=name)
+
+    # the padded carry itself, step by step: finite, zero bank and alpha
+    # outside the real region, cache values in [0, 1]
+    st = state if n_classes > 1 else jax.tree.map(lambda a: a[None], state)
+    _, s, d = st.sv_x.shape
+
+    def body(carry, xy):
+        xb, yb = xy
+        k_bb = ops.rbf_matrix(xb, xb, GAMMA, impl=interp)
+        carry = ops.train_step_padded(
+            *carry, xb, targets(yb), k_bb, table, budget=b.budget,
+            lambda_=b.lambda_, gamma=b.gamma, batch_size=b.batch_size,
+            maintenance=b.maintenance, merge_batch=b.merge_batch,
+            impl=interp)
+        sv, al, km = carry[:3]
+        finite = jnp.all(jnp.isfinite(sv)) & jnp.all(jnp.isfinite(al)) \
+            & jnp.all(jnp.isfinite(km))
+        pad_sv = jnp.max(jnp.abs(sv.at[:, :s, :d].set(0.0)))
+        pad_al = jnp.max(jnp.abs(al.at[:, :, :s].set(0.0)))
+        return carry, (finite, pad_sv, pad_al, jnp.min(km), jnp.max(km))
+
+    carry0 = (*ops.pad_fused_state(st.sv_x, st.alpha, st.kmat), st.count,
+              st.step, st.n_inserts, st.n_merges)
+    carry, (finite, pad_sv, pad_al, km_lo, km_hi) = jax.jit(
+        lambda c: jax.lax.scan(body, c, (xc, yc)))(carry0)
+    assert carry[0].shape[1] > s and carry[0].shape[2] > d
+    assert bool(jnp.all(finite))
+    assert float(jnp.max(pad_sv)) == 0.0 and float(jnp.max(pad_al)) == 0.0
+    assert float(jnp.min(km_lo)) >= 0.0 and float(jnp.max(km_hi)) <= 1.0
+    real = ops.unpad_fused_state(*carry[:3], s, d)
+    for w, g in zip((want.sv_x, want.alpha, want.kmat), real):
+        np.testing.assert_array_equal(np.asarray(g).reshape(w.shape),
+                                      np.asarray(w))
+
+
+def test_chunk_program_pads_state_once():
+    """``train_chunk_multiclass`` pads the (C, S, S) cache and the (C, S, d)
+    bank once before its loop and slices them once after it: the loop body
+    holds no pad or slice of either."""
+    from repro.core.multiclass import (init_multiclass_state,
+                                       train_chunk_multiclass)
+    c = 3
+    cfg = MulticlassSVMConfig(n_classes=c, binary=_binary_cfg(
+        step_engine="pallas"))
+    state = init_multiclass_state(cfg, CARRY_DIM)
+    s = cfg.slots
+    text = train_chunk_multiclass.lower(
+        cfg, cfg.table(), state, jnp.zeros((4, 8, CARRY_DIM)),
+        jnp.zeros((4, 8), jnp.int32),
+        impl="pallas_interpret").as_text(dialect="hlo")
+    count = ops_on_shapes(text, ("pad", "slice"),
+                          {(c, s, s), (c, s, CARRY_DIM)})
+    # [outside the loop, inside it]: one per leaf, none per step
+    assert count == {"pad": [2, 0], "slice": [2, 0]}, count
 
 
 # --------------------------------------------------------------------------

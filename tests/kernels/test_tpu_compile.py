@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from helpers.hlo import ops_on_shapes
 from repro.core.lookup import MergeLookupTable
 from repro.kernels import ops
 
@@ -119,3 +120,29 @@ def test_fused_kernel_over_vmem_names_the_limit(one_chip):
         shape, dtype, sharding=one_chip)
     with pytest.raises(ValueError, match="MiB limit of a TPU core"):
         _merge_event(sd, 2, 4096, D)
+
+
+def test_chunk_program_carries_state_without_copies(one_chip):
+    """The compiled chunk program of the fused step keeps the lane-padded
+    state in its loop: the kernel updates the carried bank and cache in
+    place, with no pad, slice or copy of either per step."""
+    from repro.core import BSGDConfig, MulticlassSVMConfig
+    from repro.core.bsgd import SVMState
+    from repro.core.multiclass import train_chunk_multiclass
+    sd = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    cfg = MulticlassSVMConfig(n_classes=C, binary=BSGDConfig(
+        budget=S - B, lambda_=1e-3, gamma=2.0**-10, batch_size=B,
+        use_kernel_cache=True, step_engine="pallas"))
+    i32 = lambda: sd((C,), jnp.int32)
+    state = SVMState(sv_x=sd((C, S, D)), alpha=sd((C, S)), count=i32(),
+                     step=i32(), n_inserts=i32(), n_merges=i32(),
+                     kmat=sd((C, S, S)))
+    text = train_chunk_multiclass.lower(
+        cfg, _table(sd), state, sd((4, B, D)), sd((4, B), jnp.int32),
+        impl="pallas").compile().as_text()
+    sp, dp = -(-S // 128) * 128, -(-D // 128) * 128
+    count = ops_on_shapes(text, ("pad", "slice", "copy"),
+                          {(C, S, S), (C, S, D), (C, sp, sp), (C, sp, dp)})
+    assert all(inside == 0 for _, inside in count.values()), count
+    assert count["pad"][0] == 2 and count["slice"][0] == 2, count
