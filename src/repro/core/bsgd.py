@@ -380,6 +380,19 @@ def carries_padded(cfg: BSGDConfig, impl: str) -> bool:
     return cfg.step_engine == "pallas" and kops.runs_pallas(impl)
 
 
+def stream_kernel(cfg: BSGDConfig, impl: str, state: SVMState) -> str:
+    """The train-step kernel that trains ``state`` under ``cfg``, by name in
+    ``kops.FUSED_KERNELS``: ``"ref"`` unless a scan carries the padded
+    state (``carries_padded``), else ``kops.fused_kernel`` of the state's
+    lane-padded shapes (``kops.pad_fused_state``)."""
+    if not carries_padded(cfg, impl):
+        return "ref"
+    lane = lambda n: -(-n // 128) * 128
+    s, d = state.sv_x.shape[-2:]
+    return kops.fused_kernel(lane(s), lane(d), cfg.grid_size,
+                             lane(cfg.batch_size), state.sv_x.dtype.itemsize)
+
+
 def scan_fused(cfg: BSGDConfig, table, state: SVMState, xs, batch, *,
                impl: str) -> SVMState:
     """``lax.scan`` of fused Pallas steps with the lane-padded state in the
@@ -796,15 +809,17 @@ def _fit_stream(batch_size: int, source, chunk_fn, state, *,
                 epochs: int, seed: int, ckpt_dir, ckpt_every: int,
                 max_chunks, keep_last: int, prefetch: int = 0, stage=None,
                 publish=None, publish_every: int = 0, retry=None,
-                report=None, skip_chunks=(), guard=None):
+                report=None, skip_chunks=(), guard=None,
+                kernel: str = "ref"):
     """Shared multi-epoch streaming driver (see ``fit_stream`` for the
     contract).  ``publish(state)`` fires every ``publish_every`` chunks (and
     once at the very end) — the ``ModelBank`` snapshot hook.  Resume walks
     back past torn/corrupt checkpoint steps to the newest verifiable one
     (``checkpoint.latest_verifiable_step``); ``retry``/``report``/
     ``skip_chunks``/``guard`` are the §16 resilience hooks threaded into
-    every epoch."""
-    with obs.span("fit.stream"):
+    every epoch.  ``kernel`` (``stream_kernel``) rides on the ``fit.stream``
+    span as its index in ``kops.FUSED_KERNELS``."""
+    with obs.span("fit.stream", kernel=kops.FUSED_KERNELS.index(kernel)):
         from .. import checkpoint as ckpt
 
         dim = source.dim
@@ -1039,7 +1054,8 @@ def fit_stream(cfg: BSGDConfig, source, *, epochs: int = 1, seed: int = 0,
                        publish_every=publish_every, retry=retry,
                        report=report, skip_chunks=skip_chunks,
                        guard=_make_guard(guard_finite, debug_invariants,
-                                         cfg, report))
+                                         cfg, report),
+                       kernel=stream_kernel(cfg, impl, state))
 
 
 def accuracy(state: SVMState, x, y, gamma, **kw) -> jax.Array:

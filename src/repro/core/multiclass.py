@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from . import budget as budget_mod
 from .bsgd import (BSGDConfig, SVMState, _device_stage, _fit_stream,
                    _make_guard, _make_publish, _stream_epoch, carries_padded,
-                   init_state, insert_from_rows, scan_fused,
+                   init_state, insert_from_rows, scan_fused, stream_kernel,
                    train_step_from_rows)
 from ..kernels import ops as kops
 
@@ -363,7 +363,8 @@ def fit_multiclass_stream(cfg: MulticlassSVMConfig, source, *,
                        publish_every=publish_every, retry=retry,
                        report=report, skip_chunks=skip_chunks,
                        guard=_make_guard(guard_finite, debug_invariants,
-                                         cfg.binary, report))
+                                         cfg.binary, report),
+                       kernel=stream_kernel(cfg.binary, impl, state))
 
 
 def fit_multiclass_loop(cfg: MulticlassSVMConfig, x, y, *, epochs: int = 1,

@@ -32,11 +32,20 @@ several copies of the cache at once.  Rows are read and written through
 their aligned (8, W) sublane tile, columns through their aligned (S, 128)
 lane tile (Mosaic indexes the lane axis only at multiples of 128), and
 slot vectors are (1, S) rows.  Scalars come from one-hot reductions — the
-TPU vector unit has no per-lane gather.  VMEM budget: the class's (S, S)
-cache and (S, D) bank blocks, double-buffered in and out, dominate
-(``fused_compiler_params`` sizes the limit from them).  Keep ``slots`` and
-``dim`` at multiples of 128 in production configs to avoid the pad/slice
-copy in ``ops.merge_event``.
+TPU vector unit has no per-lane gather.
+
+The event reaches the cache through an accessor with ``row`` /
+``set_row`` / ``set_col``: ``VmemCache`` over the class's VMEM block (this
+kernel, and the whole-block fused train step), or ``HbmCache`` over the
+class's cache left in HBM, whose rows and columns move through small VMEM
+tiles by DMA (``train_step.train_step_hbm_pallas``).  The arithmetic is
+the same code either way.
+
+VMEM budget: the class's (S, S) cache and (S, D) bank blocks,
+double-buffered in and out, dominate (``fused_compiler_params`` sizes the
+limit from them, and refuses a class block past a core's VMEM).  Keep
+``slots`` and ``dim`` at multiples of 128 in production configs to avoid
+the pad/slice copy in ``ops.merge_event``.
 """
 from __future__ import annotations
 
@@ -119,6 +128,72 @@ def set_elem(ref, i, j, v):
                                            jnp.asarray(v, ref.dtype), blk)
 
 
+class VmemCache:
+    """A class's (S, S) kernel cache held whole in a VMEM ref: rows and
+    columns through its aligned tiles."""
+
+    def __init__(self, ref):
+        self.ref = ref
+
+    def row(self, i):
+        return get_row(self.ref, i)
+
+    def set_row(self, i, row):
+        set_row(self.ref, i, row)
+
+    def set_col(self, j, col):
+        set_col(self.ref, j, col)
+
+
+class HbmCache:
+    """A class's (S, S) kernel cache left in HBM (``memory_space=ANY``).
+
+    Mosaic copies only whole tiles of a tiled HBM array, so a row moves
+    through its aligned (8, S) sublane tile (``rows``, VMEM scratch) and a
+    column through its aligned (S, 128) lane tile (``cols``): a write reads
+    the tile, edits it in VMEM and writes it back.  Each copy is waited for
+    before the next starts, so the writes land in program order, as the
+    VMEM block's do.
+    """
+
+    def __init__(self, hbm, rows, cols, sem):
+        self.hbm, self.rows, self.cols, self.sem = hbm, rows, cols, sem
+        self.s = hbm.shape[0]
+
+    def _copy(self, src, dst):
+        cp = pltpu.make_async_copy(src, dst, self.sem)
+        cp.start()
+        cp.wait()
+
+    def _row_tile(self, i):
+        t = _sublanes(self.rows)
+        base = pl.multiple_of((i // t) * t, t)
+        return base, self.hbm.at[pl.ds(base, t), :]
+
+    def row(self, i):
+        i = jnp.clip(i, 0, self.s - 1)
+        base, tile = self._row_tile(i)
+        self._copy(tile, self.rows)
+        return get_row(self.rows, i - base)
+
+    def set_row(self, i, row):
+        @pl.when(i < self.s)
+        def _():
+            base, tile = self._row_tile(i)
+            self._copy(tile, self.rows)
+            set_row(self.rows, i - base, row)
+            self._copy(self.rows, tile)
+
+    def set_col(self, j, col):
+        @pl.when(j < self.s)
+        def _():
+            base = pl.multiple_of((j // LANE) * LANE, LANE)
+            tile = self.hbm.at[:, pl.ds(base, LANE)]
+            self._copy(tile, self.cols)
+            set_col(self.cols, j - base, col)
+            self._copy(self.cols, tile)
+
+
 def lookup_chunks(alpha, kappa_row, a_min, h_tab, wd_tab, *, g: int,
                   block_s: int):
     """Lookup-WD ``(wd, h)`` of every candidate against one fixed partner.
@@ -150,16 +225,16 @@ def lookup_chunks(alpha, kappa_row, a_min, h_tab, wd_tab, *, g: int,
             jnp.concatenate(h_parts, axis=1))
 
 
-def merge_event_refs(count, alpha_ref, sv_ref, km_ref, h_tab, wd_tab, *,
+def merge_event_refs(count, alpha_ref, sv_ref, cache, h_tab, wd_tab, *,
                      g: int, block_s: int):
-    """One whole merge event, in place on one class's VMEM blocks.
+    """One whole merge event, in place on one class's blocks.
 
     count: () int32 (over budget); alpha_ref: (1, S) storage dtype; sv_ref:
-    (S, D) storage dtype; km_ref: (S, S) fp32; tables: (G, G) fp32 values.
-    Every read precedes every write.  Shared by ``_merge_event_kernel`` (one
-    event per launch) and the fused train-step megakernel
-    (``kernels.train_step``), which chains these events as its maintenance
-    rounds without leaving VMEM.
+    (S, D) storage dtype; cache: the class's (S, S) fp32 kernel cache as a
+    ``VmemCache`` or ``HbmCache``; tables: (G, G) fp32 values.  Every read
+    precedes every write.  Shared by ``_merge_event_kernel`` (one event per
+    launch) and both fused train-step kernels (``kernels.train_step``),
+    which chain these events as their maintenance rounds.
     """
     alpha = alpha_ref[...].astype(jnp.float32)           # (1, S)
     s = alpha.shape[1]
@@ -172,7 +247,7 @@ def merge_event_refs(count, alpha_ref, sv_ref, km_ref, h_tab, wd_tab, *,
     a_min = _pick(alpha, iota, i_min)
 
     # 2. kappa row = cache row i_min (kmat is symmetric).
-    kappa_row = get_row(km_ref, i_min)                   # (1, S)
+    kappa_row = cache.row(i_min)                         # (1, S)
 
     # 3. Lookup-WD scoring in block_s chunks (hat-basis bilinear, both
     #    tables interpolated per chunk — merge_lookup's trick).
@@ -196,8 +271,8 @@ def merge_event_refs(count, alpha_ref, sv_ref, km_ref, h_tab, wd_tab, *,
     lk = _safe_log(kap_m)
     a_z = (a_min * exp_f32((1.0 - h_m) ** 2 * lk)
            + a_j * exp_f32(h_m**2 * lk))
-    row_j = get_row(km_ref, j_star)
-    row_last = get_row(km_ref, last)
+    row_j = cache.row(j_star)
+    row_last = cache.row(last)
     x_i = get_row(sv_ref, i_min)
     x_j = get_row(sv_ref, j_star)
     v_last = get_row(sv_ref, last)
@@ -224,10 +299,10 @@ def merge_event_refs(count, alpha_ref, sv_ref, km_ref, h_tab, wd_tab, *,
     t2 = jnp.where(has_partner, hi, s)
     r1 = jnp.where(has_partner, r_merge, r_remove)
 
-    set_row(km_ref, t1, r1)
-    set_row(km_ref, t2, r_move)
-    set_col(km_ref, t1, r1.reshape(s, 1))
-    set_col(km_ref, t2, r_move.reshape(s, 1))
+    cache.set_row(t1, r1)
+    cache.set_row(t2, r_move)
+    cache.set_col(t1, r1.reshape(s, 1))
+    cache.set_col(t2, r_move.reshape(s, 1))
     set_row(sv_ref, t1, jnp.where(has_partner, z, v_last))
     set_row(sv_ref, t2, v_last)
 
@@ -248,24 +323,32 @@ def _merge_event_kernel(count_ref, over_ref, alpha_ref, sv_ref, kmat_ref,
 
     @pl.when(over_ref[c] > 0)
     def _():
-        merge_event_refs(count_ref[c], alpha_out, sv_out, kmat_out,
-                         h_tab_ref[...], wd_tab_ref[...], g=g,
-                         block_s=block_s)
+        merge_event_refs(count_ref[c], alpha_out, sv_out,
+                         VmemCache(kmat_out), h_tab_ref[...],
+                         wd_tab_ref[...], g=g, block_s=block_s)
 
 
-def fused_compiler_params(name: str, *, block_bytes: int, temp_bytes: int):
+def vmem_need(block_bytes: int, temp_bytes: int) -> int:
+    """VMEM a fused kernel that holds one class per grid step asks for: the
+    pipeline double-buffers every block, the body keeps about
+    ``temp_bytes`` of values live, and Mosaic keeps some for itself."""
+    return 2 * block_bytes + temp_bytes + VMEM_HEADROOM_BYTES
+
+
+def fused_compiler_params(name: str, *, block_bytes: int, temp_bytes: int,
+                          what: str):
     """Mosaic parameters for a fused kernel that holds one class per step.
 
-    The pipeline double-buffers every block, and the body keeps about
-    ``temp_bytes`` of values live.  Asks for that much VMEM, and raises,
-    naming the limit, where it exceeds what a TPU core has.
+    Asks for ``vmem_need`` of VMEM, and raises, naming the limit and
+    ``what`` the kernel holds in VMEM per class (and so what to lower),
+    where that exceeds what a TPU core has.
     """
-    need = 2 * block_bytes + temp_bytes + VMEM_HEADROOM_BYTES
+    need = vmem_need(block_bytes, temp_bytes)
     if need > VMEM_LIMIT_BYTES:
         raise ValueError(
             f"{name}: one class block needs ~{need / 2**20:.0f} MiB of VMEM, "
             f"over the {VMEM_LIMIT_BYTES / 2**20:.0f} MiB limit of a TPU "
-            "core; lower the budget (slots) or the feature dim")
+            f"core; {what}")
     return pltpu.CompilerParams(dimension_semantics=("arbitrary",),
                                 vmem_limit_bytes=need)
 
@@ -315,7 +398,11 @@ def merge_event_pallas(sv_x, alpha, kmat, count, over, h_table, wd_table, *,
         ],
         input_output_aliases={2: 0, 3: 1, 4: 2},
         compiler_params=fused_compiler_params(
-            "merge_event", block_bytes=block_bytes, temp_bytes=temp_bytes),
+            "merge_event", block_bytes=block_bytes, temp_bytes=temp_bytes,
+            what="it holds each class's whole (S, S) cache and (S, D) bank "
+                 "in VMEM: lower the budget (slots) or the feature dim, or "
+                 "train with step_engine='pallas', whose fused step keeps "
+                 "the cache in HBM at this size"),
         interpret=interpret,
     )(count.astype(jnp.int32), over.astype(jnp.int32), alpha, sv_x,
       kmat.astype(jnp.float32), h_table.astype(jnp.float32),

@@ -13,6 +13,7 @@ multiples, re-slicing, and scalar/1-D massaging.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from functools import partial
 
@@ -291,6 +292,22 @@ def multi_merge_scores(alpha, kappa_rows, valid, a_min, table, *,
 # --------------------------------------------------------------------------
 # Fused train step (margin + insert + event rounds, one launch chain)
 # --------------------------------------------------------------------------
+# what trained a stream (the ``kernel`` attribute of ``fit.stream``, by
+# index): the jnp path or composed engine, or one of the two fused kernels
+FUSED_KERNELS = ("ref", "vmem", "hbm")
+
+
+def fused_kernel(s: int, d: int, g: int, b: int, itemsize: int = 4) -> str:
+    """Which fused train-step kernel runs at padded slots ``s``, feature dim
+    ``d``, table grid ``g`` and minibatch rows ``b`` (bank ``itemsize``):
+    ``"vmem"`` (``train_step_pallas``, each class's cache a VMEM block)
+    where that kernel's VMEM need is within a core's limit, else ``"hbm"``
+    (``train_step_hbm_pallas``, the cache left in HBM)."""
+    need = merge_event_kernel.vmem_need(
+        *train_step_kernel.train_step_vmem(s, d, g, b, itemsize))
+    return "vmem" if need <= merge_event_kernel.VMEM_LIMIT_BYTES else "hbm"
+
+
 def pad_fused_state(sv_x, alpha, kmat):
     """The fused step's stacked state in the kernel's lane-padded layout.
 
@@ -322,11 +339,15 @@ def train_step_padded(sv_p, al_p, km_p, count, step, n_inserts, n_merges, xb,
     ``pad_fused_state``: returns the updated padded blocks and counters.
 
     Only the minibatch (``xb``, ``yb``, ``k_bb``, a few KB) is padded here.
-    The kernel updates the state blocks in place, so a ``lax.scan`` that
-    carries them copies nothing per step.  The pad region stays as the
-    kernel leaves it: zero bank lanes and slots, zero alpha, and cache rows
-    and columns of values in [0, 1] that nothing in the real region reads.
-    ``impl`` must resolve to ``"pallas"`` or ``"pallas_interpret"``.
+    The kernel is ``fused_kernel`` of the padded shapes: the whole-block
+    ``train_step_pallas`` where its VMEM need fits a core, else
+    ``train_step_hbm_pallas`` (named scope ``train_step.hbm``), which keeps
+    the cache in HBM.  Either updates the state blocks in place, so a
+    ``lax.scan`` that carries them copies nothing per step.  The pad region
+    stays as the kernel leaves it: zero bank lanes and slots, zero alpha,
+    and cache rows and columns of values in [0, 1] that nothing in the real
+    region reads.  ``impl`` must resolve to ``"pallas"`` or
+    ``"pallas_interpret"``.
     """
     impl = _resolve(impl)
     if impl == "ref":
@@ -336,8 +357,15 @@ def train_step_padded(sv_p, al_p, km_p, count, step, n_inserts, n_merges, xb,
         xb_p = _pad_to_lane(xb, (0, 1))
         kbb_p = _pad_to_lane(k_bb, (0, 1))
         yb_p = _pad_to_lane(yb, 1)
-    sv_n, al_n, km_n, cnt_n, nins_n, nmrg_n = \
-        train_step_kernel.train_step_pallas(
+    _, s, d = sv_p.shape
+    kernel = fused_kernel(s, d, table.h_table.shape[0], xb_p.shape[0],
+                          sv_p.dtype.itemsize)
+    fn = (train_step_kernel.train_step_pallas if kernel == "vmem"
+          else train_step_kernel.train_step_hbm_pallas)
+    scope = (contextlib.nullcontext() if kernel == "vmem"
+             else jax.named_scope("train_step.hbm"))
+    with scope:
+        sv_n, al_n, km_n, cnt_n, nins_n, nmrg_n = fn(
             sv_p, al_p, km_p, count, step, n_inserts, n_merges, xb_p,
             yb_p[:, None, :], kbb_p, table.h_table, table.wd_table,
             budget=budget, lambda_=lambda_, gamma=gamma,
@@ -359,7 +387,8 @@ def train_step(sv_x, alpha, kmat, count, step, n_inserts, n_merges, xb, yb,
     Pegasos shrink/insert + maintenance event rounds (DESIGN.md §12).
 
     sv_x: (C, slots, d); alpha: (C, slots); kmat: (C, slots, slots) fp32
-    kernel cache (REQUIRED — the fused step maintains it in VMEM); count /
+    kernel cache (REQUIRED — the fused step maintains it: as VMEM blocks up
+    to the size ``fused_kernel`` allows, in HBM past it); count /
     step / n_inserts / n_merges: (C,) int32; xb: (batch, d); yb: (C, batch)
     one-vs-rest targets; k_bb: (batch, batch) = k(xb, xb); ``table`` a
     ``MergeLookupTable``.  ``maintenance`` is ``"merge"`` or
@@ -370,9 +399,10 @@ def train_step(sv_x, alpha, kmat, count, step, n_inserts, n_merges, xb, yb,
     alpha, kmat, count, step, n_inserts, n_merges)``.  Oracle and CPU
     production path: ``ref.train_step_fused``.
 
-    The Pallas path pads the state, runs ``train_step_padded`` and slices
-    back: four copies of the whole state per call.  A scan of steps pads
-    once and carries the padded blocks instead (``core.bsgd.scan_fused``).
+    The Pallas path pads the state, runs ``train_step_padded`` (which picks
+    the kernel by ``fused_kernel``) and slices back: four copies of the
+    whole state per call.  A scan of steps pads once and carries the padded
+    blocks instead (``core.bsgd.scan_fused``).
     """
     impl = _resolve(impl)
     if impl == "ref":

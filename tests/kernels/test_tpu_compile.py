@@ -122,27 +122,50 @@ def test_fused_kernel_over_vmem_names_the_limit(one_chip):
         _merge_event(sd, 2, 4096, D)
 
 
+def _chunk_program(sd, c, s, d):
+    """The compiled text of the fused chunk program at ``c`` classes of
+    ``s`` slots (budget + batch) and ``d`` features, and how many pads,
+    slices and copies of a state-sized buffer it holds outside and inside
+    its loop."""
+    from repro.core import BSGDConfig, MulticlassSVMConfig
+    from repro.core.bsgd import SVMState
+    from repro.core.multiclass import train_chunk_multiclass
+    cfg = MulticlassSVMConfig(n_classes=c, binary=BSGDConfig(
+        budget=s - B, lambda_=1e-3, gamma=2.0**-10, batch_size=B,
+        use_kernel_cache=True, step_engine="pallas"))
+    i32 = lambda: sd((c,), jnp.int32)
+    state = SVMState(sv_x=sd((c, s, d)), alpha=sd((c, s)), count=i32(),
+                     step=i32(), n_inserts=i32(), n_merges=i32(),
+                     kmat=sd((c, s, s)))
+    text = train_chunk_multiclass.lower(
+        cfg, _table(sd), state, sd((4, B, d)), sd((4, B), jnp.int32),
+        impl="pallas").compile().as_text()
+    sp, dp = -(-s // 128) * 128, -(-d // 128) * 128
+    return text, ops_on_shapes(text, ("pad", "slice", "copy"),
+                               {(c, s, s), (c, s, d), (c, sp, sp),
+                                (c, sp, dp)})
+
+
 def test_chunk_program_carries_state_without_copies(one_chip):
     """The compiled chunk program of the fused step keeps the lane-padded
     state in its loop: the kernel updates the carried bank and cache in
     place, with no pad, slice or copy of either per step."""
-    from repro.core import BSGDConfig, MulticlassSVMConfig
-    from repro.core.bsgd import SVMState
-    from repro.core.multiclass import train_chunk_multiclass
     sd = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
         shape, dtype, sharding=one_chip)
-    cfg = MulticlassSVMConfig(n_classes=C, binary=BSGDConfig(
-        budget=S - B, lambda_=1e-3, gamma=2.0**-10, batch_size=B,
-        use_kernel_cache=True, step_engine="pallas"))
-    i32 = lambda: sd((C,), jnp.int32)
-    state = SVMState(sv_x=sd((C, S, D)), alpha=sd((C, S)), count=i32(),
-                     step=i32(), n_inserts=i32(), n_merges=i32(),
-                     kmat=sd((C, S, S)))
-    text = train_chunk_multiclass.lower(
-        cfg, _table(sd), state, sd((4, B, D)), sd((4, B), jnp.int32),
-        impl="pallas").compile().as_text()
-    sp, dp = -(-S // 128) * 128, -(-D // 128) * 128
-    count = ops_on_shapes(text, ("pad", "slice", "copy"),
-                          {(C, S, S), (C, S, D), (C, sp, sp), (C, sp, dp)})
+    _, count = _chunk_program(sd, C, S, D)
+    assert all(inside == 0 for _, inside in count.values()), count
+    assert count["pad"][0] == 2 and count["slice"][0] == 2, count
+
+
+def test_chunk_program_past_one_vmem_block_keeps_cache_in_hbm(one_chip):
+    """At budget 4,096 and 784 features (the mnist8m cell) a class's cache
+    is past one VMEM block: the chunk program compiles with the HBM kernel
+    in place of the whole-block one, and its loop still holds no pad,
+    slice or copy of the (C, S, S) cache or the (C, S, d) bank."""
+    sd = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    text, count = _chunk_program(sd, 10, 4096 + B, 784)
+    assert "train_step_hbm_pallas" in text
+    assert "train_step_pallas" not in text
     assert all(inside == 0 for _, inside in count.values()), count
     assert count["pad"][0] == 2 and count["slice"][0] == 2, count
