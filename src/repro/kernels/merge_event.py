@@ -12,8 +12,9 @@ event per class per launch:
   * the kappa row read straight from the class's VMEM-resident cache block
     (``kmat`` is symmetric: row ``i_min`` IS ``k(x_min, .)``);
   * Lookup-WD candidate scoring with the same gather-free hat-basis bilinear
-    trick as ``merge_lookup`` (one ``(bS, G) x (G, G)`` MXU matmul per table
-    per chunk against the VMEM-resident tables);
+    trick as ``merge_lookup`` (one ``(bS, G) x (G, G)`` MXU matmul per chunk
+    against the VMEM-resident WD table), then the h table at the chosen
+    pair alone (one ``(8, G) x (G, G)`` matmul, ``lookup_h``);
   * the merged point's cache row derived IN the kernel from the two parent
     rows (the log-space combine of ``core.kernel_cache`` — the z-row never
     round-trips through HBM);
@@ -194,35 +195,51 @@ class HbmCache:
             self._copy(self.cols, tile)
 
 
-def lookup_chunks(alpha, kappa_row, a_min, h_tab, wd_tab, *, g: int,
-                  block_s: int):
-    """Lookup-WD ``(wd, h)`` of every candidate against one fixed partner.
+def lookup_chunks(alpha, kappa_row, a_min, wd_tab, *, g: int, block_s: int):
+    """Lookup-WD score of every candidate against one fixed partner.
 
-    alpha, kappa_row: (1, S) fp32; a_min: () fp32; tables (G, G).  Scored in
-    ``block_s`` chunks so the (bS, G) hat-weight matrices stay small; the
+    alpha, kappa_row: (1, S) fp32; a_min: () fp32; wd_tab: (G, G).  Scored
+    in ``block_s`` chunks so the (bS, G) hat-weight matrices stay small; the
     chunks are joined as (1, bS) rows along the lane axis because Mosaic
-    cannot concatenate 1-D vectors.  Returns two (1, S) rows.
+    cannot concatenate 1-D vectors.  Returns three (1, S) rows: the WD and
+    the clipped table coordinates ``(m, kap)`` the chunks scored, from which
+    ``lookup_h`` reads the chosen candidate's h.
     """
     s = alpha.shape[1]
-    wd_parts, h_parts = [], []
+    wd_parts, m_parts, kap_parts = [], [], []
     for start in range(0, s, block_s):
         al_c = alpha[0, start:start + block_s]
         kap_c = kappa_row[0, start:start + block_s]
         denom = a_min + al_c
         m = jnp.clip(a_min / jnp.where(denom == 0.0, 1.0, denom), 0.0, 1.0)
         kap = jnp.clip(kap_c, 0.0, 1.0)
-        w_m = _hat_weights(m, g)                         # (bS, G)
-        w_k = _hat_weights(kap, g)
         rows_wd = jax.lax.dot_general(
-            w_m, wd_tab, (((1,), (0,)), ((), ())), precision=HIGHEST,
-            preferred_element_type=jnp.float32)
-        rows_h = jax.lax.dot_general(
-            w_m, h_tab, (((1,), (0,)), ((), ())), precision=HIGHEST,
-            preferred_element_type=jnp.float32)
-        wd_parts.append((denom * denom * jnp.sum(rows_wd * w_k, axis=1))[None])
-        h_parts.append(jnp.sum(rows_h * w_k, axis=1)[None])
-    return (jnp.concatenate(wd_parts, axis=1),
-            jnp.concatenate(h_parts, axis=1))
+            _hat_weights(m, g), wd_tab, (((1,), (0,)), ((), ())),
+            precision=HIGHEST, preferred_element_type=jnp.float32)
+        wd_parts.append(
+            (denom * denom * jnp.sum(rows_wd * _hat_weights(kap, g),
+                                     axis=1))[None])
+        m_parts.append(m[None])
+        kap_parts.append(kap[None])
+    join = lambda parts: jnp.concatenate(parts, axis=1)
+    return join(wd_parts), join(m_parts), join(kap_parts)
+
+
+def lookup_h(m, kap, j, h_tab, *, g: int):
+    """The h table at the candidate in slot ``j`` of the (1, S) coordinate
+    rows ``m``, ``kap`` that ``lookup_chunks`` scored: the chunks'
+    hat-basis bilinear on an (8, G) block, that one point broadcast over 8
+    sublanes for Mosaic's tiling.  Every row is the same point; returns row
+    0 as a () fp32."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, m.shape, 1)
+    w_m = _hat_weights(jnp.full((8,), _pick(m, iota, j)), g)     # (8, G)
+    w_k = _hat_weights(jnp.full((8,), _pick(kap, iota, j)), g)
+    rows = jax.lax.dot_general(w_m, h_tab, (((1,), (0,)), ((), ())),
+                               precision=HIGHEST,
+                               preferred_element_type=jnp.float32)
+    h = jnp.sum(rows * w_k, axis=1, keepdims=True)               # (8, 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, h.shape, 0)
+    return jnp.sum(jnp.where(sub == 0, h, 0.0))
 
 
 def merge_event_refs(count, alpha_ref, sv_ref, cache, h_tab, wd_tab, *,
@@ -249,10 +266,10 @@ def merge_event_refs(count, alpha_ref, sv_ref, cache, h_tab, wd_tab, *,
     # 2. kappa row = cache row i_min (kmat is symmetric).
     kappa_row = cache.row(i_min)                         # (1, S)
 
-    # 3. Lookup-WD scoring in block_s chunks (hat-basis bilinear, both
-    #    tables interpolated per chunk — merge_lookup's trick).
-    wd, h = lookup_chunks(alpha, kappa_row, a_min, h_tab, wd_tab, g=g,
-                          block_s=block_s)
+    # 3. Lookup-WD scoring in block_s chunks (hat-basis bilinear against
+    #    the WD table — merge_lookup's trick); h only for the chosen pair.
+    wd, m, kap = lookup_chunks(alpha, kappa_row, a_min, wd_tab, g=g,
+                               block_s=block_s)
     valid = active & (alpha * a_min > 0) & (iota != i_min)
     wd = jnp.where(valid, wd, WD_INVALID)
 
@@ -262,8 +279,8 @@ def merge_event_refs(count, alpha_ref, sv_ref, cache, h_tab, wd_tab, *,
     has_partner = wd_mn < NO_PARTNER
     last = count - 1
 
-    # 4. merge math on the chosen pair
-    h_m = _pick(h, iota, j_star)
+    # 4. merge math on the chosen pair (h_m goes unused on removal)
+    h_m = lookup_h(m, kap, j_star, h_tab, g=g)
     k_ij = _pick(kappa_row, iota, j_star)
     kap_m = jnp.clip(k_ij, 0.0, 1.0)
     a_j = _pick(alpha, iota, j_star)

@@ -18,9 +18,10 @@ grid step:
      same blocks in a loop: single-pair rounds run
      ``merge_event.merge_event_refs`` verbatim; multi-merge rounds retire up
      to P disjoint same-sign pairs per round (top-P smallest |alpha| fixed
-     partners, Lookup-WD scored against the VMEM-resident tables, greedy
-     disjoint choice, fused z-row writes + targeted-move compaction — the
-     in-kernel restatement of ``core.budget._multi_merge_once``).
+     partners, Lookup-WD scored against the VMEM-resident WD table, h
+     looked up for the chosen pairs only, greedy disjoint choice, fused
+     z-row writes + targeted-move compaction — the in-kernel restatement
+     of ``core.budget._multi_merge_once``).
 
 Two kernels differ only in where the class's ``(S, S)`` cache lives (one
 body, ``_train_step_kernel``):
@@ -62,8 +63,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .merge_event import (LANE, HbmCache, VmemCache, _first_where, _pick,
                           fused_compiler_params, get_row, lookup_chunks,
-                          merge_event_refs, merge_event_vmem, set_col,
-                          set_elem, set_row)
+                          lookup_h, merge_event_refs, merge_event_vmem,
+                          set_col, set_elem, set_row)
 from .merge_lookup import HIGHEST, WD_INVALID
 from .ref import NO_PARTNER, _safe_log, exp_f32
 
@@ -171,14 +172,16 @@ def _multi_merge_refs(count, alpha_ref, sv_ref, km_ref, h_tab, wd_tab, *,
     kappa_rows = [get_row(km_ref, a) for a in a_idx]
 
     # 3. Lookup-WD scoring per pair row (merge_lookup's gather-free
-    #    hat-basis bilinear against the resident tables).
-    wd_rows, h_rows = [], []
+    #    hat-basis bilinear against the resident WD table); h is looked up
+    #    for the chosen pairs only, in step 5.
+    wd_rows, m_rows, kap_rows = [], [], []
     for q in range(p):
         valid_q = active & (alpha * a_min[q] > 0) & (iota != a_idx[q])
-        wd_q, h_q = lookup_chunks(alpha, kappa_rows[q], a_min[q], h_tab,
-                                  wd_tab, g=g, block_s=block_s)
+        wd_q, m_q, kap_q = lookup_chunks(alpha, kappa_rows[q], a_min[q],
+                                         wd_tab, g=g, block_s=block_s)
         wd_rows.append(jnp.where(valid_q, wd_q, WD_INVALID))
-        h_rows.append(h_q)
+        m_rows.append(m_q)
+        kap_rows.append(kap_q)
 
     # 4. greedy disjoint pair choice in |alpha| order (budget's loop).
     excess = count - budget
@@ -209,7 +212,7 @@ def _multi_merge_refs(count, alpha_ref, sv_ref, km_ref, h_tab, wd_tab, *,
     h_star, lk_ab, az, z_pts, lz_rows, write_i, hole_i = \
         [], [], [], [], [], [], []
     for q in range(p):
-        hq = _pick(h_rows[q], iota, b_idx[q])
+        hq = lookup_h(m_rows[q], kap_rows[q], b_idx[q], h_tab, g=g)
         k_ab = _pick(kappa_rows[q], iota, b_idx[q])
         a_b = _pick(alpha, iota, b_idx[q])
         lkq = _safe_log(jnp.clip(k_ab, 0.0, 1.0))

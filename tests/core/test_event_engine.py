@@ -24,6 +24,7 @@ from repro.core import (BSGDConfig, MulticlassSVMConfig, default_table, fit,
 from repro.core.budget import _merge_once
 from repro.data import make_blobs_multiclass, make_two_moons, train_test_split
 from repro.kernels import ops as kops
+from repro.kernels import ref
 
 GAMMA = 0.5
 
@@ -78,6 +79,22 @@ def test_merge_event_round_matches_merge_once(impl, seed):
                                    atol=max(tol, 1e-6))
 
 
+def _chosen_merge(alpha, kmat, count, table):
+    """``(i, j, h, kappa)`` of the pair ``ref.merge_event`` merges in one
+    class: its fixed partner, the Lookup-WD best same-sign partner, the h
+    table there and their kernel value, as floats."""
+    s = alpha.shape[0]
+    idx = jnp.arange(s)
+    active = idx < count
+    i = int(jnp.argmin(jnp.where(active, jnp.abs(alpha), jnp.inf)))
+    m, kap = ref.merge_coords(alpha[i], alpha, kmat[i])
+    wd = (alpha[i] + alpha) ** 2 * ref.bilinear_lookup(table.wd_table, m, kap)
+    valid = active & (alpha * alpha[i] > 0) & (idx != i)
+    j = int(jnp.argmin(jnp.where(valid, wd, jnp.inf)))
+    h = ref.bilinear_lookup(table.h_table, m, kap)[j]
+    return i, j, float(h), float(kap[j])
+
+
 def test_merge_event_removal_fallback_round():
     """A class whose min-|alpha| SV has no same-sign partner must fall back
     to removal inside the fused round (mixed with a merging class)."""
@@ -99,9 +116,14 @@ def test_merge_event_removal_fallback_round():
         # class 0 removed its positive: survivors all negative, mass intact
         surv = np.asarray(al2[0][:9])
         assert (surv < 0).all(), impl
-        # class 1 merged: same-sign mass preserved to fp tolerance
-        assert np.isclose(np.asarray(al2[1][:9]).sum(),
-                          float(alpha[1].sum()), atol=5e-3), impl
+        # class 1 merged: the merged slot holds a_i k^((1-h)^2) + a_j k^(h^2)
+        # on the pair and the h ref.merge_event chose (less than a_i + a_j
+        # for k < 1, so the class's alpha sum is not kept)
+        i, j, h, k = _chosen_merge(alpha[1], kmat[1], count[1], table)
+        a_i, a_j = (float(alpha[1][q]) for q in (i, j))
+        a_z = a_i * k ** ((1.0 - h) ** 2) + a_j * k ** (h**2)
+        np.testing.assert_allclose(float(al2[1][min(i, j)]), a_z, rtol=1e-6,
+                                   err_msg=impl)
         merged = []
         for q in range(2):
             s1, a1_, k1, _, info = _merge_once(sv[q], alpha[q], kmat[q],
