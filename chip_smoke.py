@@ -62,7 +62,7 @@ from repro.data.synthetic import (make_blobs_multiclass,  # noqa: E402
 from repro.kernels import ops, ref  # noqa: E402
 from repro.launch.train import svm_stream_loop  # noqa: E402
 
-# (a) SUSY shape: benchmarks/common.py's gamma and lambda
+# (a) SUSY shape: the paper's SUSY gamma and lambda
 SUSY_DIM, SUSY_GAMMA, SUSY_LAMBDA = 18, 2.0**-7, 1e-5
 BUDGET, CHUNK_ROWS, N_CHUNKS = 1024, 65536, 4
 # (b) the class axis: C=16 one-vs-rest at dim 512; blobs close enough

@@ -11,8 +11,8 @@ What it shows (DESIGN.md §9):
      state, equal accuracy;
   3. a run killed mid-epoch (``max_chunks``) resumes from its every-2-chunks
      checkpoint and finishes BITWISE identical to the uninterrupted run;
-  4. streamed rows/sec (the number ``benchmarks/bench_stream.py`` records to
-     ``BENCH_stream.json``, together with peak RSS).
+  4. streamed rows/sec on this host (rows/s on the chip is the benchmark's
+     ``train_rows_per_s``: ``python3 bench/run.py``, PERF.md).
 """
 import argparse
 import os

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
-Entry points (the training and serving CLIs, the benchmarks,
-``chip_smoke.py``) call ``enable_compile_cache`` once before they compile.
+Entry points (the training and serving CLIs, ``chip_smoke.py``; the
+benchmark under ``bench/`` keeps its own copy) call ``enable_compile_cache`` once before they compile.
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing
 is set here.  Otherwise the cache lives at ``<checkout>/.jax_cache``: a
 fixed path, because the path is part of how a cached program is found

@@ -16,7 +16,9 @@ paper's four budget-maintenance solvers), adapted to fixed shapes:
     minibatches are the TPU-friendly configuration (see DESIGN.md §3).
 
 Everything jits; ``train_epoch`` wraps the step in ``lax.scan`` so a whole
-pass over the data is one XLA program.
+pass over the data is one XLA program.  The entry points here are the C = 1
+case of the one training engine, ``core.multiclass``, which vmaps the
+per-class row step defined here (``train_step_from_rows``).
 """
 from __future__ import annotations
 
@@ -26,11 +28,9 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from . import budget as budget_mod
 from . import kernel_cache
-from .. import obs
 from .lookup import MergeLookupTable, default_table
 from ..kernels import ops as kops
 
@@ -124,30 +124,18 @@ class BSGDConfig:
     method: str = "lookup-wd"          # gss | gss-precise | lookup-h | lookup-wd
     batch_size: int = 1
     grid_size: int = 400
-    dtype: str = "float32"             # alpha / margin arithmetic dtype
-    sv_dtype: str | None = None        # SV row storage (bf16 halves HBM + gather
-                                       # traffic at scale; kappa error ~1e-3)
-    use_kernel_cache: bool = False     # persistent SV-SV kernel matrix: kappa
-                                       # rows are read, not recomputed
+    dtype: str = "float32"
+    sv_dtype: str | None = None
+    use_kernel_cache: bool = False
     maintenance: str = "merge"         # merge | multi-merge | removal |
                                        # removal-project | quantized
-    merge_batch: int = 4               # P pairs per fused multi-merge event
-    unroll_maintenance: bool = False   # inline batch_size masked events instead
-                                       # of a while_loop: bitwise loop-parity
-                                       # under vmap (core.budget docstring);
-                                       # compile size grows with batch_size
-    maintenance_engine: str = "xla"    # xla | pallas — pallas runs the fused
-                                       # all-class merge-event kernel on the
-                                       # sorted-excess schedule (DESIGN.md §11)
-    step_engine: str = "composed"      # composed | pallas — pallas fuses the
-                                       # whole step (margin + insert + event
-                                       # rounds) into one launch chain per
-                                       # class block (DESIGN.md §12)
-    solver: str = "bsgd"               # bsgd | bdca — primal Pegasos SGD or
-                                       # dual coordinate ascent (core.bdca);
-                                       # the §14 solver contract
-    bdca_rounds: int = 2               # ascent sweeps per step (bdca only)
-    bdca_C: float = 1.0                # dual box 0 <= alpha <= C (bdca only)
+    merge_batch: int = 4
+    unroll_maintenance: bool = False
+    maintenance_engine: str = "xla"    # xla | pallas
+    step_engine: str = "composed"      # composed | pallas
+    solver: str = "bsgd"               # bsgd | bdca
+    bdca_rounds: int = 2
+    bdca_C: float = 1.0
 
     def __post_init__(self):
         if self.maintenance not in budget_mod.STRATEGIES:
@@ -259,8 +247,8 @@ def insert_from_rows(cfg: BSGDConfig, state: SVMState, xb, yb, k_b,
 
     # margins under the current model; the kernel rows k(xb, sv) are kept —
     # they double as the cache update on insert (zero extra kernel evals)
-    # mask by the state's own width: callers may replay a step under a
-    # one-larger budget on the same arrays (see bench_table3 decision_stats)
+    # mask by the state's own width: a caller may replay a step under a
+    # one-larger budget on the same arrays
     active = jnp.arange(state.alpha.shape[0]) < state.count
     f = jnp.matmul(k_b.astype(state.alpha.dtype),
                    jnp.where(active, state.alpha, 0.0),
@@ -341,42 +329,26 @@ def train_step(cfg: BSGDConfig, table, state: SVMState, xb, yb, *,
                impl: str = "auto") -> SVMState:
     """One minibatch step + budget maintenance (``cfg.solver`` dispatch).
 
-    xb: (batch, dim), yb: (batch,) in {-1, +1}.
+    xb: (batch, dim), yb: (batch,) in {-1, +1}.  The class-axis step
+    ``multiclass.train_step_ovr`` at C = 1: the state lifted to a leading
+    axis of one, ``yb`` the one class's targets.
     """
-    if cfg.solver == "bdca":
-        # dual coordinate ascent (core.bdca) — same fused margin rows, same
-        # maintenance drain; only the insert/update half differs
-        from . import bdca
-        k_b = kops.rbf_matrix(xb, state.sv_x, cfg.gamma, impl=impl)
-        k_bb = kops.rbf_matrix(xb, xb, cfg.gamma, impl=impl)
-        return bdca.train_step_from_rows(cfg, table, state, xb, yb, k_b,
-                                         k_bb, impl=impl)
-    if cfg.step_engine == "pallas":
-        # the fused megakernel is class-batched; the binary step lifts to
-        # C = 1 (margin + insert + event rounds in one launch chain)
-        k_bb = kops.rbf_matrix(xb, xb, cfg.gamma, impl=impl)
-        sv, al, km, cnt, st_, nin, nmg = (a[0] for a in kops.train_step(
-            state.sv_x[None], state.alpha[None], state.kmat[None],
-            state.count[None], state.step[None], state.n_inserts[None],
-            state.n_merges[None], xb, yb[None], k_bb, table,
-            budget=cfg.budget, lambda_=cfg.lambda_, gamma=cfg.gamma,
-            batch_size=cfg.batch_size, maintenance=cfg.maintenance,
-            merge_batch=cfg.merge_batch,
-            unroll=cfg.batch_size if cfg.unroll_maintenance else 0,
-            impl=impl))
-        return SVMState(sv_x=sv, alpha=al, count=cnt, step=st_,
-                        n_inserts=nin, n_merges=nmg, kmat=km)
-    k_b = kops.rbf_matrix(xb, state.sv_x, cfg.gamma, impl=impl)   # (batch, slots)
-    k_bb = (kops.rbf_matrix(xb, xb, cfg.gamma, impl=impl)         # (batch, batch)
-            if cfg.use_kernel_cache else None)
-    return train_step_from_rows(cfg, table, state, xb, yb, k_b, k_bb,
-                                impl=impl)
+    from . import multiclass   # the engine; it imports this module
+    return _one_class(multiclass.train_step_ovr, cfg, table, state, xb,
+                      yb[None], impl=impl)
+
+
+def _one_class(fn, cfg, table, state: SVMState, *args, **kw) -> SVMState:
+    """``fn(cfg, table, state, ...)`` of the class-axis engine on a binary
+    ``state``: lifted to one class (C = 1), the axis dropped after."""
+    out = fn(cfg, table, jax.tree.map(lambda a: a[None], state), *args, **kw)
+    return jax.tree.map(lambda a: a[0], out)
 
 
 def carries_padded(cfg: BSGDConfig, impl: str) -> bool:
     """Whether a scan of ``cfg``'s steps runs the fused Pallas kernel and so
-    carries the state in its lane-padded layout (``scan_fused``).  The
-    ``ref`` path and the composed engine carry the state as it is."""
+    carries the state in its lane-padded layout (``multiclass.scan_ovr``).
+    The ``ref`` path and the composed engine carry the state as it is."""
     return cfg.step_engine == "pallas" and kops.runs_pallas(impl)
 
 
@@ -393,53 +365,6 @@ def stream_kernel(cfg: BSGDConfig, impl: str, state: SVMState) -> str:
                              lane(cfg.batch_size), state.sv_x.dtype.itemsize)
 
 
-def scan_fused(cfg: BSGDConfig, table, state: SVMState, xs, batch, *,
-               impl: str) -> SVMState:
-    """``lax.scan`` of fused Pallas steps with the lane-padded state in the
-    carry: the real state of a scan of ``kops.train_step``, bitwise.
-
-    ``state`` is stacked (every leaf has a leading (C,) axis); ``batch(x)``
-    maps one scanned element of ``xs`` to the minibatch ``xb`` (batch, d)
-    and its one-vs-rest targets (C, batch).  The state is padded once before
-    the loop (named scope ``train_chunk.pad``) and sliced once after it
-    (``train_chunk.unpad``); in between the kernel updates the carried
-    blocks in place, so no step copies the state.
-    """
-    _, s, d = state.sv_x.shape
-
-    def body(carry, x):
-        xb, y_ovr = batch(x)
-        k_bb = kops.rbf_matrix(xb, xb, cfg.gamma, impl=impl)
-        return kops.train_step_padded(
-            *carry, xb, y_ovr, k_bb, table, budget=cfg.budget,
-            lambda_=cfg.lambda_, gamma=cfg.gamma,
-            batch_size=cfg.batch_size, maintenance=cfg.maintenance,
-            merge_batch=cfg.merge_batch, impl=impl), ()
-
-    with jax.named_scope("train_chunk.pad"):
-        padded = kops.pad_fused_state(state.sv_x, state.alpha, state.kmat)
-    carry = (*padded, state.count, state.step, state.n_inserts,
-             state.n_merges)
-    (sv, al, km, cnt, step, nin, nmg), _ = jax.lax.scan(body, carry, xs)
-    with jax.named_scope("train_chunk.unpad"):
-        sv, al, km = kops.unpad_fused_state(sv, al, km, s, d)
-    return SVMState(sv_x=sv, alpha=al, count=cnt, step=step, n_inserts=nin,
-                    n_merges=nmg, kmat=km)
-
-
-def scan_fused_binary(cfg: BSGDConfig, table, state: SVMState, xs, batch, *,
-                      impl: str) -> SVMState:
-    """``scan_fused`` for a binary state: lifted to C = 1 as ``train_step``
-    lifts it; ``batch(x)`` gives ``(xb, yb)`` with yb (batch,)."""
-    def lifted(x):
-        xb, yb = batch(x)
-        return xb, yb[None]
-
-    out = scan_fused(cfg, table, jax.tree.map(lambda a: a[None], state), xs,
-                     lifted, impl=impl)
-    return jax.tree.map(lambda a: a[0], out)
-
-
 @partial(jax.jit, static_argnames=("cfg", "impl"))
 def train_epoch(cfg: BSGDConfig, table, state: SVMState, x, y, perm, *,
                 impl: str = "auto") -> SVMState:
@@ -452,25 +377,11 @@ def train_epoch(cfg: BSGDConfig, table, state: SVMState, x, y, perm, *,
         this epoch (rows past the last full ``batch_size`` multiple are
         dropped).
     Returns the updated ``SVMState``.  The streamed counterpart over a chunk
-    source is ``train_epoch_stream``.
+    source is ``train_epoch_stream``; the class-axis epoch at C = 1.
     """
-    n = perm.shape[0]
-    steps = n // cfg.batch_size
-    order = perm[: steps * cfg.batch_size].reshape(steps, cfg.batch_size)
-
-    if carries_padded(cfg, impl):
-        return scan_fused_binary(
-            cfg, table, state, order,
-            lambda idx: (jnp.take(x, idx, axis=0), jnp.take(y, idx, axis=0)),
-            impl=impl)
-
-    def scan_body(st, batch_idx):
-        xb = jnp.take(x, batch_idx, axis=0)
-        yb = jnp.take(y, batch_idx, axis=0)
-        return train_step(cfg, table, st, xb, yb, impl=impl), ()
-
-    state, _ = jax.lax.scan(scan_body, state, order)
-    return state
+    from . import multiclass
+    return _one_class(multiclass.scan_epoch_ovr, cfg, table, state, x, y,
+                      perm, lambda yb: yb[None], impl=impl)
 
 
 def fit(cfg: BSGDConfig, x, y, *, epochs: int = 1, seed: int = 0,
@@ -502,439 +413,22 @@ def fit(cfg: BSGDConfig, x, y, *, epochs: int = 1, seed: int = 0,
     return state
 
 
-# ---------------------------------------------------------------------------
-# Streaming epochs: chunked host pipeline -> one donated-state program/chunk
-# ---------------------------------------------------------------------------
-
 @partial(jax.jit, static_argnames=("cfg", "impl"), donate_argnums=(2,))
 def train_chunk(cfg: BSGDConfig, table, state: SVMState, xc, yc, *,
                 impl: str = "auto") -> SVMState:
     """One resident chunk as a single donated-state XLA program.
 
     ``xc: (steps, batch, dim)``, ``yc: (steps, batch)`` — the chunk already
-    shuffled and reshaped into minibatches on the host.  The scan body is the
-    same traced ``train_step`` as the in-memory ``train_epoch``, so the hot
-    path is identical; donating ``state`` lets XLA update the budgeted model
-    in place while chunks stream through.  The fused Pallas step carries
-    its lane-padded state through the scan (``scan_fused``).
+    shuffled and reshaped into minibatches on the host.  The class-axis chunk
+    program (``multiclass.train_chunk_ovr``) at C = 1: its scan body is the
+    same traced step as the in-memory ``train_epoch``, so the hot path is
+    identical; donating ``state`` lets XLA update the budgeted model in
+    place while chunks stream through.  The fused Pallas step carries its
+    lane-padded state through the scan (``multiclass.scan_ovr``).
     """
-    def body(st, xy):
-        xb, yb = xy
-        return train_step(cfg, table, st, xb, yb, impl=impl), ()
-
-    with jax.named_scope("train_chunk"):
-        if carries_padded(cfg, impl):
-            return scan_fused_binary(cfg, table, state, (xc, yc),
-                                     lambda xy: xy, impl=impl)
-        state, _ = jax.lax.scan(body, state, (xc, yc))
-    return state
-
-
-def _assemble_chunks(source, key, *, batch_size: int, start_chunk: int,
-                     end: int, carry, stage=None, retry=None, report=None,
-                     skip_chunks=()):
-    """Host-side assembly of one epoch: yield ``(pos, xc, yc, carry)``.
-
-    The single definition of the chunk -> minibatch-block transform shared by
-    the synchronous and prefetched streaming paths (bitwise-identity between
-    them is BY CONSTRUCTION: the async path runs this very generator on a
-    worker thread).  Per chunk: prepend the previous chunk's remainder rows,
-    reshape the batch-aligned part to ``(steps, batch, dim)`` (``xc/yc`` are
-    None for a chunk that yields no full batch), and copy the new remainder
-    out of the chunk buffer (O(chunk) residency promise).  ``stage`` maps the
-    assembled blocks (the ``jax.device_put`` hook of the prefetched path).
-    ``retry``/``report``/``skip_chunks`` pass straight to ``iter_epoch`` —
-    a quarantined (or skipped) chunk contributes no rows, so the carry flows
-    across it and the surviving batch sequence is bitwise the one of a run
-    where the chunk never existed (DESIGN.md §16).
-    """
-    from ..data import stream as stream_mod
-
-    cx, cy = carry if carry is not None else (None, None)
-    for pos, x, y in stream_mod.iter_epoch(source, key,
-                                           start_chunk=start_chunk,
-                                           end_chunk=end, retry=retry,
-                                           report=report,
-                                           skip_chunks=skip_chunks):
-        x, y = np.asarray(x), np.asarray(y)
-        if cx is not None and cx.size:
-            x = np.concatenate([cx.astype(x.dtype, copy=False), x])
-            y = np.concatenate([cy.astype(y.dtype, copy=False), y])
-        steps = x.shape[0] // batch_size
-        used = steps * batch_size
-        # copy the (< batch_size rows) remainder: a view would keep the whole
-        # chunk buffer alive through the next chunk's load (O(chunk) promise)
-        cx, cy = x[used:].copy(), y[used:].copy()
-        xc = yc = None
-        if steps:
-            xc = x[:used].reshape(steps, batch_size, x.shape[1])
-            yc = y[:used].reshape(steps, batch_size)
-            if stage is not None:
-                with obs.span("stream.stage"):
-                    xc, yc = stage(xc, yc)
-        yield pos, xc, yc, (cx, cy)
-
-
-def _stage_chunks(gen, depth: int):
-    """Run an assembly generator ``depth`` items ahead on a worker thread.
-
-    The prefetched streaming pipeline: the worker parses/shuffles/assembles
-    (and, via the generator's ``stage`` hook, ``jax.device_put``s) chunk
-    ``i+1``..``i+depth`` while the consumer's donated-state scan of chunk
-    ``i`` runs.  A bounded queue applies backpressure; a worker exception is
-    re-raised on the CONSUMER's thread at the point the failing chunk would
-    have been yielded, and abandoning the generator (early close, consumer
-    exception) stops the worker promptly — no hung thread either way.
-    """
-    import queue as queue_mod
-    import threading
-
-    q = queue_mod.Queue(maxsize=depth)
-    stop = threading.Event()
-    _DONE, _FAIL = object(), object()
-
-    def _put(item) -> bool:
-        try:
-            q.put_nowait(item)
-            return True
-        except queue_mod.Full:
-            pass
-        with obs.span("stream.backpressure"):
-            while not stop.is_set():
-                try:
-                    q.put(item, timeout=0.1)
-                    return True
-                except queue_mod.Full:
-                    continue
-        return False
-
-    ctx = obs.context()
-
-    def work():
-        with obs.attach(ctx):
-            try:
-                for item in gen:
-                    if not _put((None, item)):
-                        return
-                _put((_DONE, None))
-            except BaseException as e:  # noqa: BLE001 — re-raised on
-                _put((_FAIL, e))            # the consumer's thread
-
-    t = threading.Thread(target=work, daemon=True, name="chunk-stager")
-    t.start()
-    try:
-        while True:
-            tag, item = q.get()
-            if tag is _DONE:
-                return
-            if tag is _FAIL:
-                raise item
-            yield item
-    finally:
-        stop.set()
-        t.join(timeout=5.0)
-
-
-@jax.jit
-def _tree_all_finite(tree):
-    """One fused all-finite reduction over the inexact leaves of a pytree —
-    the O(1)-sync non-finite sentinel of the streaming guard (int counters
-    are always finite and are skipped)."""
-    leaves = [leaf for leaf in jax.tree.leaves(tree)
-              if jnp.issubdtype(leaf.dtype, jnp.inexact)]
-    if not leaves:
-        return jnp.bool_(True)
-    return jnp.all(jnp.stack([jnp.all(jnp.isfinite(leaf)) for leaf in leaves]))
-
-
-@dataclasses.dataclass
-class _StreamGuard:
-    """Per-chunk training guards for the streaming drivers (DESIGN.md §16).
-
-    ``finite=True`` snapshots the state before each chunk program and, after
-    it, runs ONE fused ``isfinite`` all-reduce over the float leaves (a
-    single scalar sync).  On trip the chunk is rolled back and skipped —
-    a poisoned state is never kept, never checkpointed, never published.
-    ``check`` (optional, debug mode) runs a host-side validator — the cache
-    invariant checker — on every accepted state.
-    """
-
-    finite: bool = True
-    report: object = None       # faults.ResilienceReport (rollback tally)
-    check: object = None        # callable(state) -> None, raises on violation
-
-
-def _make_guard(guard_finite: bool, debug_invariants: bool, binary_cfg,
-                report):
-    """Resolve the ``guard_finite``/``debug_invariants`` fit-driver knobs to
-    a ``_StreamGuard`` (or None — the exact pre-resilience chunk loop)."""
-    if not (guard_finite or debug_invariants):
-        return None
-    check = None
-    if debug_invariants and binary_cfg.use_kernel_cache:
-        def check(state):
-            kernel_cache.check_invariants(state.kmat, state.sv_x, state.count,
-                                          binary_cfg.gamma)
-    return _StreamGuard(finite=guard_finite, report=report, check=check)
-
-
-def _stream_epoch(chunk_fn, state, source, *, batch_size: int, key,
-                  start_chunk: int = 0, carry=None, on_chunk=None,
-                  max_chunks: int | None = None, prefetch: int = 0,
-                  stage=None, retry=None, report=None, skip_chunks=(),
-                  guard=None, epoch: int = 0):
-    """Generic one-epoch streaming driver shared by binary and multi-class.
-
-    ``chunk_fn(state, xc, yc) -> state`` runs one jitted chunk program.
-    Rows left over when a chunk is not a multiple of ``batch_size`` *carry*
-    into the next chunk (so the realized batch sequence equals the in-memory
-    one on the concatenated order); the final sub-batch rows of the epoch are
-    dropped, matching ``train_epoch``'s truncation.  Chunks are staged in the
-    source's own dtypes (no forced cast — streamed and in-memory training see
-    the same arrays); checkpointed carry rows are stored as float32 and cast
-    back on resume.  ``on_chunk(state, pos, carry)`` fires after each chunk
-    program — the checkpoint hook.
-
-    ``prefetch > 0`` moves the whole host pipeline (chunk load, shuffle,
-    carry splice, minibatch reshape, and — for the default single-device
-    programs — the ``jax.device_put`` transfer) onto a background worker
-    running up to ``prefetch`` chunks ahead of the device, double-buffered
-    against the donated-state scan of the current chunk.  The worker runs the
-    same ``_assemble_chunks`` generator as the sync path, so the realized
-    batch sequence (and therefore training) is bitwise identical.  ``stage``
-    overrides the staging transform (``None`` with a custom distributed
-    ``chunk_fn`` keeps host arrays — pjit places them per its in_shardings).
-
-    Resilience (all default-off — the zero-fault path is the exact pre-PR
-    loop): ``retry``/``report``/``skip_chunks`` flow into the ingest layer
-    (``iter_epoch`` — transient-failure retries, quarantine-as-skip);
-    ``guard`` (a ``_StreamGuard``) snapshots the state per chunk and rolls
-    back any chunk whose resulting state has a non-finite float leaf, so a
-    NaN/Inf row (or a diverged update) can never persist into checkpoints or
-    published ``ServeModel`` snapshots — the rollback fires BEFORE
-    ``on_chunk``.
-
-    Each chunk is one ``fit.chunk`` span (``repro.obs``; attributes
-    ``epoch``, ``pos``, ``rows``) over its ``stream.wait`` for the staged
-    chunk, its ``chunk.launch``, the guard's ``guard.finite`` and
-    ``on_chunk``.
-
-    Returns ``(state, next_chunk, carry, chunks_run)``; ``next_chunk <
-    source.n_chunks`` means the epoch was cut short by ``max_chunks``.
-    """
-    # resolve the budget to an exclusive end position up front so chunks past
-    # it are never read from the source
-    end = (source.n_chunks if max_chunks is None
-           else min(source.n_chunks, start_chunk + max_chunks))
-    gen = _assemble_chunks(source, key, batch_size=batch_size,
-                           start_chunk=start_chunk, end=end, carry=carry,
-                           stage=stage if prefetch else None, retry=retry,
-                           report=report, skip_chunks=skip_chunks)
-    items = _stage_chunks(gen, prefetch) if prefetch else gen
-    out_carry = carry
-    try:
-        while True:
-            with obs.span("fit.chunk") as chunk:
-                with obs.span("stream.wait") as wait:
-                    item = next(items, None)
-                    if item is None:          # the epoch's end, no chunk
-                        wait.discard()
-                        chunk.discard()
-                if item is None:
-                    break
-                pos, xc, yc, out_carry = item
-                chunk.set(epoch=epoch, pos=pos,
-                          rows=0 if xc is None else xc.shape[0] * xc.shape[1])
-                if xc is not None:
-                    if guard is not None and guard.finite:
-                        # the chunk program donates its input state, so the
-                        # last-good snapshot must be copied out BEFORE the
-                        # launch
-                        snap = jax.tree.map(jnp.copy, state)
-                        with obs.span("chunk.launch"):
-                            new_state = chunk_fn(state, xc, yc)
-                        with obs.span("guard.finite"):
-                            finite = bool(_tree_all_finite(new_state))
-                        if finite:
-                            state = new_state
-                        else:
-                            state = snap   # roll back + skip the poisoned
-                            if guard.report is not None:  # chunk wholesale
-                                guard.report.note_rollback(pos)
-                    else:
-                        with obs.span("chunk.launch"):
-                            state = chunk_fn(state, xc, yc)
-                    if guard is not None and guard.check is not None:
-                        guard.check(state)
-                if on_chunk is not None:
-                    on_chunk(state, pos, out_carry)
-    finally:
-        if prefetch:
-            items.close()                 # stop the stager on any exit
-    if out_carry is None:
-        out_carry = (np.zeros((0, source.dim), np.float32),
-                     np.zeros((0,), np.float32))
-    return state, end, out_carry, end - start_chunk
-
-
-def _ckpt_template(state: SVMState, batch_size: int, dim: int):
-    """Target tree for the streaming checkpoint: model state + epoch RNG key
-    + the (padded, fixed-shape) inter-chunk carry rows."""
-    return {
-        "state": state,
-        "epoch_key": jax.random.PRNGKey(0),
-        "carry_x": jnp.zeros((batch_size - 1, dim), jnp.float32),
-        "carry_y": jnp.zeros((batch_size - 1,), jnp.float32),
-        "carry_n": jnp.zeros((), jnp.int32),
-    }
-
-
-def _pad_carry(carry, batch_size: int, dim: int):
-    cx, cy = carry
-    n = cx.shape[0]
-    px = np.zeros((batch_size - 1, dim), np.float32)
-    py = np.zeros((batch_size - 1,), np.float32)
-    px[:n], py[:n] = cx, cy
-    return px, py, np.int32(n)
-
-
-def _device_stage(xc, yc):
-    """Default staging for the prefetched single-device path: start the
-    host->device transfer of an assembled block from the worker thread, so
-    the copy (and not just the parse) overlaps the previous chunk's scan."""
-    return jax.device_put(xc), jax.device_put(yc)
-
-
-def _fit_stream(batch_size: int, source, chunk_fn, state, *,
-                epochs: int, seed: int, ckpt_dir, ckpt_every: int,
-                max_chunks, keep_last: int, prefetch: int = 0, stage=None,
-                publish=None, publish_every: int = 0, retry=None,
-                report=None, skip_chunks=(), guard=None,
-                kernel: str = "ref"):
-    """Shared multi-epoch streaming driver (see ``fit_stream`` for the
-    contract).  ``publish(state)`` fires every ``publish_every`` chunks (and
-    once at the very end) — the ``ModelBank`` snapshot hook.  Resume walks
-    back past torn/corrupt checkpoint steps to the newest verifiable one
-    (``checkpoint.latest_verifiable_step``); ``retry``/``report``/
-    ``skip_chunks``/``guard`` are the §16 resilience hooks threaded into
-    every epoch.  ``kernel`` (``stream_kernel``) rides on the ``fit.stream``
-    span as its index in ``kops.FUSED_KERNELS``."""
-    with obs.span("fit.stream", kernel=kops.FUSED_KERNELS.index(kernel)):
-        from .. import checkpoint as ckpt
-
-        dim = source.dim
-        n_chunks = source.n_chunks
-        start_epoch, start_chunk = 0, 0
-        carry, resume_key = None, None
-        if ckpt_dir:
-            latest = ckpt.latest_step(ckpt_dir)
-            if latest is not None:
-                # a torn/bit-flipped newest step (crash mid-save outside the
-                # atomic-rename path, disk corruption) must not kill the
-                # restart: fall back to the newest step whose checksums verify
-                verified = ckpt.latest_verifiable_step(ckpt_dir)
-                if verified is None:
-                    raise ValueError(
-                        f"{ckpt_dir}: checkpoint steps "
-                        f"{ckpt.all_steps(ckpt_dir)} exist but none verify "
-                        "(manifest/arrays corrupt) — "
-                        "refusing to silently restart from scratch")
-                latest = verified
-            if latest is not None:
-                meta = ckpt.load_metadata(ckpt_dir, latest)
-                if meta.get("kind") != "stream-epoch":
-                    raise ValueError(f"{ckpt_dir}: step {latest} is not a "
-                                     "streaming checkpoint")
-                # the cursor is only meaningful against the same shuffle and
-                # the same chunking — a silent mismatch would train some rows
-                # twice and others never, so refuse instead
-                if meta["seed"] != seed:
-                    raise ValueError(
-                        f"{ckpt_dir}: checkpoint was written with seed="
-                        f"{meta['seed']}, resume called with seed={seed}")
-                if meta["n_chunks"] != n_chunks:
-                    raise ValueError(
-                        f"{ckpt_dir}: checkpoint cursor is against "
-                        f"{meta['n_chunks']} chunks, source now has "
-                        f"{n_chunks} — re-chunked data cannot resume "
-                        "mid-epoch")
-                tree = ckpt.load(ckpt_dir, latest,
-                                 _ckpt_template(state, batch_size, dim))
-                state = tree["state"]
-                start_epoch, start_chunk = meta["epoch"], meta["next_chunk"]
-                resume_key = tree["epoch_key"]    # the interrupted epoch's key
-                cn = int(tree["carry_n"])
-                carry = (np.asarray(tree["carry_x"])[:cn],
-                         np.asarray(tree["carry_y"])[:cn])
-                if start_chunk >= n_chunks:   # checkpoint at an epoch end
-                    start_epoch, start_chunk, carry = start_epoch + 1, 0, None
-                    resume_key = None
-
-        budget_left = max_chunks
-        base_key = jax.random.PRNGKey(seed)
-        for epoch in range(start_epoch, epochs):
-            # the resumed epoch continues under its checkpointed RNG key
-            # (equal, by the seed guard above, to the rederived one); later
-            # epochs fold
-            epoch_key = (resume_key if epoch == start_epoch and
-                         resume_key is not None
-                         else jax.random.fold_in(base_key, epoch))
-
-            def save(st, pos, cr, *, _epoch=epoch, _key=epoch_key):
-                done = pos + 1
-                if (publish is not None and publish_every
-                        and done % publish_every == 0):
-                    with obs.span("publish"):
-                        publish(st)
-                if not (ckpt_dir and ckpt_every
-                        and done % ckpt_every == 0):
-                    return
-                px, py, cn = _pad_carry(cr, batch_size, dim)
-                ckpt.save(ckpt_dir, _epoch * n_chunks + done,
-                          {"state": st, "epoch_key": _key, "carry_x": px,
-                           "carry_y": py, "carry_n": cn},
-                          keep_last=keep_last,
-                          metadata={"kind": "stream-epoch",
-                                    "epoch": _epoch, "next_chunk": done,
-                                    "n_chunks": n_chunks, "seed": seed})
-
-            state, next_chunk, carry, ran = _stream_epoch(
-                chunk_fn, state, source, batch_size=batch_size,
-                key=epoch_key, start_chunk=start_chunk, carry=carry,
-                on_chunk=save, max_chunks=budget_left, prefetch=prefetch,
-                stage=stage, retry=retry, report=report,
-                skip_chunks=skip_chunks, guard=guard, epoch=epoch)
-            if budget_left is not None:
-                budget_left -= ran
-            if next_chunk < n_chunks:         # cut short by max_chunks
-                if publish is not None:
-                    with obs.span("publish"):
-                        publish(state)
-                return state
-            jax.block_until_ready(state.alpha)  # sync only at epoch end
-            start_chunk, carry = 0, None  # sub-batch remainder dropped
-        if publish is not None:
-            with obs.span("publish"):
-                publish(state)                # the final model always lands
-        return state
-
-
-def _make_publish(bank, gamma, bank_dtype):
-    """Build the ``ModelBank`` snapshot hook for a streaming trainer.
-
-    The chunk programs DONATE the state, so the next chunk invalidates the
-    buffers a naive export would alias — the hook copies the state out first
-    and publishes a genuinely immutable ``ServeModel`` snapshot.
-    """
-    if bank is None:
-        return None
-    from .predict import export_model   # lazy: predict imports this module
-
-    def publish(state):
-        snap = jax.tree.map(jnp.copy, state)
-        bank.publish(export_model(snap, gamma, bank_dtype=bank_dtype))
-
-    return publish
+    from . import multiclass
+    return _one_class(multiclass.train_chunk_ovr, cfg, table, state, xc,
+                      yc[:, None], impl=impl)
 
 
 def train_epoch_stream(cfg: BSGDConfig, table, state: SVMState, source, *,
@@ -968,18 +462,13 @@ def train_epoch_stream(cfg: BSGDConfig, table, state: SVMState, source, *,
     caller's input buffers are consumed — keep using the returned state (or
     use ``fit_stream``, which copies a provided state up front).
     """
-    stage = _device_stage if chunk_fn is None else None
-    if chunk_fn is None:
-        def chunk_fn(st, xc, yc):
-            return train_chunk(cfg, table, st, xc, yc, impl=impl)
-    state, next_chunk, carry, _ = _stream_epoch(
-        chunk_fn, state, source, batch_size=cfg.batch_size, key=key,
+    from . import multiclass
+    return multiclass.stream_epoch_ovr(
+        partial(train_chunk, cfg, table, impl=impl), state, source,
+        batch_size=cfg.batch_size, key=key, chunk_fn=chunk_fn,
         start_chunk=start_chunk, carry=carry, on_chunk=on_chunk,
-        max_chunks=max_chunks, prefetch=prefetch, stage=stage, retry=retry,
-        report=report, skip_chunks=skip_chunks)
-    if next_chunk == source.n_chunks:
-        jax.block_until_ready(state.alpha)
-    return state, next_chunk, carry
+        max_chunks=max_chunks, prefetch=prefetch, retry=retry, report=report,
+        skip_chunks=skip_chunks)[:3]
 
 
 def fit_stream(cfg: BSGDConfig, source, *, epochs: int = 1, seed: int = 0,
@@ -1037,25 +526,16 @@ def fit_stream(cfg: BSGDConfig, source, *, epochs: int = 1, seed: int = 0,
     state; a caller-provided ``state`` is copied once up front so the
     caller's arrays stay valid (same non-destructive contract as ``fit``).
     """
-    table = cfg.table()
-    if state is None:
-        state = init_state(cfg, source.dim)
-    else:
-        state = jax.tree.map(jnp.array, state)   # donation would delete it
-    stage = _device_stage if chunk_fn is None else None
-    if chunk_fn is None:
-        def chunk_fn(st, xc, yc):
-            return train_chunk(cfg, table, st, xc, yc, impl=impl)
-    return _fit_stream(cfg.batch_size, source, chunk_fn, state,
-                       epochs=epochs, seed=seed, ckpt_dir=ckpt_dir,
-                       ckpt_every=ckpt_every, max_chunks=max_chunks,
-                       keep_last=keep_last, prefetch=prefetch, stage=stage,
-                       publish=_make_publish(bank, cfg.gamma, publish_dtype),
-                       publish_every=publish_every, retry=retry,
-                       report=report, skip_chunks=skip_chunks,
-                       guard=_make_guard(guard_finite, debug_invariants,
-                                         cfg, report),
-                       kernel=stream_kernel(cfg, impl, state))
+    from . import multiclass
+    return multiclass.fit_stream_ovr(
+        cfg, partial(train_chunk, cfg, cfg.table(), impl=impl),
+        lambda: init_state(cfg, source.dim), source, state=state, impl=impl,
+        epochs=epochs, seed=seed, ckpt_dir=ckpt_dir, ckpt_every=ckpt_every,
+        max_chunks=max_chunks, keep_last=keep_last, chunk_fn=chunk_fn,
+        prefetch=prefetch, bank=bank, publish_every=publish_every,
+        publish_dtype=publish_dtype, retry=retry, guard_finite=guard_finite,
+        debug_invariants=debug_invariants, report=report,
+        skip_chunks=skip_chunks)
 
 
 def accuracy(state: SVMState, x, y, gamma, **kw) -> jax.Array:

@@ -60,7 +60,7 @@ def init_cache(slots: int, dtype=jnp.float32):
 
 
 def exact_cache(sv_x, gamma, dtype=jnp.float32):
-    """Ground-truth cache recomputed from the SV set (tests / benchmarks /
+    """Ground-truth cache recomputed from the SV set (tests /
     cache (re)builds after checkpoint restore)."""
     x = sv_x.astype(jnp.float32)
     k = ref.rbf_matrix(x, x, gamma).astype(dtype)

@@ -19,8 +19,8 @@ Two deliberate contract points, pinned by tests/core/test_online.py:
     driver introduces no randomness of its own (scoring is pure, training
     consumes batches in stream order), so two runs agree bitwise.
 
-Each chunk trains through the donated per-chunk program
-(``bsgd.train_chunk`` / ``multiclass.train_chunk_multiclass``) on its
+Each chunk trains through the class-axis engine's donated chunk program
+(``multiclass.train_chunk_ovr``; a binary model is its one class) on its
 batch-aligned prefix; the up-to-``batch_size - 1`` remainder rows of a
 chunk are scored but not trained (chunk-grained prequential — benchmarks
 size chunks as multiples of the batch so nothing is dropped).  A cold
@@ -31,11 +31,12 @@ every strategy, so matched comparisons are unaffected.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
-from .bsgd import BSGDConfig, init_state, predict, train_chunk
+from .bsgd import BSGDConfig, init_state, predict
 from .multiclass import (MulticlassSVMConfig, init_multiclass_state,
-                         predict_multiclass, train_chunk_multiclass)
+                         ovr_targets, predict_multiclass, train_chunk_ovr)
 from ..data.stream import iter_epoch
 
 
@@ -65,9 +66,15 @@ def prequential_stream(cfg, source, *, key=None, impl: str = "auto",
     if state is None:
         state = (init_multiclass_state(cfg, source.dim) if multi
                  else init_state(binary, source.dim))
-    score = predict_multiclass if multi else predict
-    train = jax.jit(train_chunk_multiclass if multi else train_chunk,
-                    static_argnames=("cfg", "impl"), donate_argnums=(2,))
+    # one chunk program for both, on the engine's stacked form: a binary
+    # model is its one class, and its ±1 labels are that class's targets
+    if multi:
+        score, unstack = predict_multiclass, lambda st: st
+        targets = lambda yc: ovr_targets(yc, cfg.n_classes, binary.dtype)
+    else:
+        score, unstack = predict, lambda st: jax.tree.map(lambda a: a[0], st)
+        state = jax.tree.map(lambda a: a[None], state)
+        targets = lambda yc: yc[None].astype(np.float32)
     bsz = binary.batch_size
     mistakes = 0
     n_rows = 0
@@ -77,7 +84,7 @@ def prequential_stream(cfg, source, *, key=None, impl: str = "auto",
         x = np.asarray(x, np.float32)
         y = np.asarray(y)
         # test ...
-        pred = np.asarray(score(state, x, binary.gamma, impl=impl))
+        pred = np.asarray(score(unstack(state), x, binary.gamma, impl=impl))
         wrong = int(np.sum(pred != y))
         mistakes += wrong
         n_rows += x.shape[0]
@@ -87,11 +94,10 @@ def prequential_stream(cfg, source, *, key=None, impl: str = "auto",
         steps = x.shape[0] // bsz
         if steps:
             xc = x[:steps * bsz].reshape(steps, bsz, -1)
-            yc = y[:steps * bsz].reshape(steps, bsz)
-            if not multi:
-                yc = yc.astype(np.float32)
-            state = train(cfg, table, state, xc, yc, impl=impl)
-    state = jax.block_until_ready(state)
+            tc = targets(y[:steps * bsz]).reshape(-1, steps, bsz)
+            state = train_chunk_ovr(binary, table, state, xc,
+                                    jnp.moveaxis(tc, 1, 0), impl=impl)
+    state = jax.block_until_ready(unstack(state))
     return {"state": state, "n_rows": n_rows, "mistakes": mistakes,
             "mistake_rate": round(mistakes / max(n_rows, 1), 4),
             "chunk_acc": chunk_acc, "chunk_mistakes": chunk_mist}

@@ -32,8 +32,9 @@ scoring).  This module is the inference half of that bargain:
 
 The distributed form (bank replicated per device, requests sharded over
 every mesh axis — zero-collective scoring) is ``core.distributed``'s
-``layout="serve"``; ``launch.serve --arch svm_bsgd`` is the driver and
-``benchmarks/bench_serve.py`` the throughput/latency artifact.
+``layout="serve"``; ``launch.serve --arch svm_bsgd`` is the driver.  Serve
+latency on the chip is measured by the benchmark (``python3 bench/run.py``,
+PERF.md).
 """
 from __future__ import annotations
 
@@ -392,7 +393,7 @@ class ModelBank:
     immutable (frozen dataclass over immutable jax arrays), so a published
     snapshot can never change under a reader — the publisher's job is to
     hand over arrays nobody mutates or donates afterwards (the trainers copy
-    out of their donated buffers first; see ``bsgd._make_publish``).
+    out of their donated buffers first; see ``multiclass._make_publish``).
     """
 
     def __init__(self, model: ServeModel | None = None):
@@ -913,8 +914,7 @@ def drive_trace(model: ServeModel, req_x, sizes, *, max_batch: int = 256,
                 queue: str = "sync") -> dict:
     """Push one request trace through a fresh warmed queue and measure it.
 
-    The shared serve-loop used by ``launch.serve_svm`` and
-    ``benchmarks.bench_serve``: submits ``sizes``-shaped requests from
+    The serve-loop of ``launch.serve_svm``: submits ``sizes``-shaped requests from
     ``req_x`` in order, drains, ASSERTS the labels are bitwise one direct
     ``predict_labels`` call (the parity gate runs on every invocation), and
     returns rows/sec + p50/p99 microbatch latency + queue stats —

@@ -269,7 +269,7 @@ class ResilienceReport:
 
     Shared across the ingest retry layer (possibly on a prefetch worker
     thread), the training guard and the serve supervisor; ``as_dict()`` is
-    the JSON-able summary the benchmarks and the live serve driver record.
+    the JSON-able summary the live serve driver records.
     """
 
     def __init__(self):

@@ -290,7 +290,7 @@ class PrefetchChunks(ChunkSource):
     carries it) — the worker itself never hangs or dies silently.
     ``iter_epoch(prefetch=depth)`` wraps and plans automatically; the
     streaming trainers go further and stage whole assembled minibatch blocks
-    (``bsgd._stage_chunks``).
+    (``multiclass._stage_chunks``).
 
     Teardown: ``cancel()`` drops the plan without waiting (the mid-epoch
     re-plan path); ``close()`` additionally JOINS the worker, guaranteeing

@@ -402,7 +402,7 @@ def train_step(sv_x, alpha, kmat, count, step, n_inserts, n_merges, xb, yb,
     The Pallas path pads the state, runs ``train_step_padded`` (which picks
     the kernel by ``fused_kernel``) and slices back: four copies of the
     whole state per call.  A scan of steps pads once and carries the padded
-    blocks instead (``core.bsgd.scan_fused``).
+    blocks instead (``core.multiclass.scan_ovr``).
     """
     impl = _resolve(impl)
     if impl == "ref":

@@ -76,8 +76,8 @@ def test_async_queue_bitwise_any_arrivals(seed, watchdog):
 def test_async_never_more_dispatches_than_sync(seed, watchdog):
     """The ISSUE 9 regression gate: for the same submit-all-then-drain
     trace, waiter-gated dispatch must coalesce at least as well as the sync
-    queue — MORE microbatches would mean the async path re-introduced the
-    per-row trickle that made BENCH_pipeline's async bar dip below 1x."""
+    queue — MORE microbatches would mean the async path re-introduced a
+    per-row trickle of dispatches, coalescing worse than the sync queue."""
     watchdog(300)
     from repro.core.predict import drive_trace, ragged_trace_sizes
     rng = np.random.default_rng(seed)

@@ -215,6 +215,23 @@ def test_bf16_bank_matches_fp32_on_margin_separated_rows(mc_model):
     np.testing.assert_array_equal(l16[sep], l32[sep])
 
 
+def test_bf16_bank_accuracy_not_below_fp32():
+    """On a held-out split the bfloat16 bank labels at least as many rows
+    correctly as the fp32 bank of the same model."""
+    from repro.data import train_test_split
+    cfg = MulticlassSVMConfig.create(5, budget=24, lambda_=1e-3, gamma=GAMMA,
+                                     batch_size=8)
+    x, y = make_blobs_multiclass(jax.random.PRNGKey(3), 960, 8, n_classes=5,
+                                 sep=2.0)
+    (xtr, ytr), (xte, yte) = train_test_split(x, y)
+    state = fit_multiclass(cfg, xtr, ytr, epochs=1, seed=0)
+    acc = {dt: float(np.mean(np.asarray(predict_labels(
+        export_model(state, GAMMA, bank_dtype=dt), xte)) == np.asarray(yte)))
+        for dt in (None, "bfloat16")}
+    assert acc[None] > 0.8, acc
+    assert acc["bfloat16"] >= acc[None], acc
+
+
 @pytest.mark.parametrize("multiclass", [False, True])
 def test_serving_midepoch_checkpoint_equals_inmemory(tmp_path, multiclass):
     """A killed streamed run's checkpoint serves bitwise like the in-memory

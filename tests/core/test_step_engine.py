@@ -7,9 +7,8 @@ The contracts pinned here (DESIGN.md §12):
     maintenance strategies, class counts, and the bf16 bank;
   * the kernel cache stays exact (== rebuild from the bank) after fused
     training;
-  * fused-vs-composed parity holds at every cell measured by
-    ``benchmarks/bench_train_step.py`` (the committed BENCH_train_step.json
-    numbers compare like for like);
+  * fused-vs-composed parity holds at one steady-state step over a grid
+    of widths, budgets and class counts (C 1 and 16);
   * the BOGD-style ``maintenance="removal-project"`` strategy matches its
     closed form and stays loop-exact under the vmapped multi-class step;
   * ``kernels.ops._pad_to_lane`` round-trips (pad then slice == identity);
@@ -101,16 +100,16 @@ def test_cache_matches_rebuild_after_fused_training():
 
 
 # --------------------------------------------------------------------------
-# parity at every cell the benchmark measures
+# parity over a grid of widths, budgets and class counts
 # --------------------------------------------------------------------------
-BENCH_CELLS = [(dim, budget, c) for dim in (64, 512)
+PARITY_CELLS = [(dim, budget, c) for dim in (64, 512)
                for budget in (256, 1024) for c in (1, 16)]
 
 
-@pytest.mark.parametrize("dim,budget,n_classes", BENCH_CELLS)
+@pytest.mark.parametrize("dim,budget,n_classes", PARITY_CELLS)
 def test_fused_step_parity_at_bench_cells(dim, budget, n_classes):
-    """One steady-state step (count == budget, events fire) per measured
-    cell of BENCH_train_step.json — the benchmark compares like for like."""
+    """One steady-state step (count == budget, events fire) per cell of a
+    grid of widths, budgets and class counts (C = 1 is the binary step)."""
     kw = dict(budget=budget, lambda_=1e-3, gamma=2.0**-7, batch_size=8,
               method="lookup-wd", use_kernel_cache=True, maintenance="merge")
     if n_classes == 1:
@@ -249,20 +248,27 @@ def test_chunk_carry_matches_per_step(n_classes, strategy):
                                       np.asarray(w))
 
 
-def test_chunk_program_pads_state_once():
-    """``train_chunk_multiclass`` pads the (C, S, S) cache and the (C, S, d)
-    bank once before its loop and slices them once after it: the loop body
-    holds no pad or slice of either."""
+@pytest.mark.parametrize("n_classes", [1, 3])
+def test_chunk_program_pads_state_once(n_classes):
+    """The chunk program (``train_chunk_multiclass``; at C = 1 the binary
+    ``bsgd.train_chunk``, which lifts the state to one class) pads the
+    (C, S, S) cache and the (C, S, d) bank once before its loop and slices
+    them once after it: the loop body holds no pad or slice of either."""
+    from repro.core import bsgd
     from repro.core.multiclass import (init_multiclass_state,
                                        train_chunk_multiclass)
-    c = 3
-    cfg = MulticlassSVMConfig(n_classes=c, binary=_binary_cfg(
-        step_engine="pallas"))
-    state = init_multiclass_state(cfg, CARRY_DIM)
+    c = n_classes
+    b = _binary_cfg(step_engine="pallas")
+    if c == 1:
+        cfg, chunk, labels = b, bsgd.train_chunk, jnp.ones((4, 8))
+        state = bsgd.init_state(b, CARRY_DIM)
+    else:
+        cfg = MulticlassSVMConfig(n_classes=c, binary=b)
+        chunk, labels = train_chunk_multiclass, jnp.zeros((4, 8), jnp.int32)
+        state = init_multiclass_state(cfg, CARRY_DIM)
     s = cfg.slots
-    text = train_chunk_multiclass.lower(
-        cfg, cfg.table(), state, jnp.zeros((4, 8, CARRY_DIM)),
-        jnp.zeros((4, 8), jnp.int32),
+    text = chunk.lower(
+        cfg, cfg.table(), state, jnp.zeros((4, 8, CARRY_DIM)), labels,
         impl="pallas_interpret").as_text(dialect="hlo")
     count = ops_on_shapes(text, ("pad", "slice"),
                           {(c, s, s), (c, s, CARRY_DIM)})

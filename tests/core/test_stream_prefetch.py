@@ -42,6 +42,20 @@ def test_prefetch_bitwise_binary(watchdog):
         _leaves_equal(ref, got)
 
 
+def test_prefetch_bitwise_libsvm_stream(tmp_path, watchdog):
+    """A LIBSVM text stream, parsed chunk by chunk (on the stager with
+    prefetch), trains bitwise the same with prefetch as without."""
+    watchdog(300)
+    from repro.data import LibsvmChunks, dump_libsvm
+    x, y = _binary(n=197)
+    path = str(tmp_path / "train.libsvm")
+    dump_libsvm(path, x, y)
+    ref = fit_stream(CFG, LibsvmChunks(path, 37, DIM), epochs=2, seed=3)
+    got = fit_stream(CFG, LibsvmChunks(path, 37, DIM), epochs=2, seed=3,
+                     prefetch=2)
+    _leaves_equal(ref, got)
+
+
 def test_prefetch_bitwise_multiclass(watchdog):
     watchdog(300)
     x, y = make_blobs_multiclass(jax.random.PRNGKey(1), 180, DIM, 3)

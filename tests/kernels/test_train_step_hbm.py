@@ -8,7 +8,7 @@ core's limit.  Here that route is reached at small shapes by lowering
 ``merge_event.VMEM_LIMIT_BYTES`` to the HBM kernel's own need, and the
 state it trains is held bitwise to the whole-block kernel's, pad region and
 counters included: one step, and 64-step chunk and epoch scans through
-``bsgd.scan_fused``.  Slots 28 and d 6 are not lane multiples; most steps
+``multiclass.scan_ovr``.  Slots 28 and d 6 are not lane multiples; most steps
 insert and merge.
 """
 import jax
@@ -117,7 +117,7 @@ def test_hbm_step_matches_whole_block(n_classes, request):
 
 
 def _scans(n_classes):
-    """The chunk and epoch programs (``bsgd.scan_fused``) over 64 steps."""
+    """The chunk and epoch programs (``multiclass.scan_ovr``) over 64 steps."""
     cfg = _cfg(n_classes)
     x, y = _stream(n_classes, jax.random.PRNGKey(n_classes))
     xc = x.reshape(STEPS, BATCH, DIM)
